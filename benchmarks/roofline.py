@@ -13,7 +13,6 @@ import os
 
 from benchmarks.common import write_csv
 from repro.configs import SHAPES, get_config
-from repro.launch.mesh import HW
 
 DRYRUN_DIR = os.environ.get("REPRO_DRYRUN_DIR", "experiments/dryrun")
 

@@ -24,6 +24,9 @@ def main() -> None:
         os.environ["REPRO_BENCH_ROUNDS"] = "5"
         os.environ["REPRO_BENCH_SMOKE"] = "1"
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         async_bench,
         client_distribution,

@@ -42,6 +42,7 @@ from repro.configs.har_mlp import fl_defaults
 from repro.core.metrics import overhead_reduction
 from repro.data import make_har_dataset
 from repro.fl import FLConfig, SchedulerConfig, run_federated
+from repro.launch.compile_cache import enable_compile_cache
 
 CUSTOM_ROUND_HELP = """
 cohort execution (O(K) rounds):
@@ -299,6 +300,7 @@ def main():
         ap.error("--trace/--profile require --record-dir")
     if args.checkpoint_every and not args.resume:
         ap.error("--checkpoint-every needs --resume DIR to write into")
+    enable_compile_cache()
     # fail fast on a bad codec spec or strategy name before the
     # (minutes-long) baseline runs
     from repro.comm import make_codec

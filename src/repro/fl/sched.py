@@ -314,6 +314,23 @@ class _RunSetup:
     n_layers: int
     r_loop: jax.Array
 
+    def initial_state(self) -> RoundState:
+        """Round 0's carried state: every client selected, nothing
+        observed yet."""
+        c = self.env.n_clients
+        return RoundState(
+            global_params=self.g0,
+            local_params=self.loc0,
+            accuracy=jnp.zeros((c,)),
+            select=jnp.ones((c,), bool),
+            pms=jnp.full((c,), self.pms0, jnp.int32),
+            rng=self.r_loop,
+            residual=self.residual0,
+            participation=jnp.zeros((c,), jnp.int32),
+            loss=jnp.zeros((c,), jnp.float32),
+            update_norm=jnp.zeros((c,), jnp.float32),
+        )
+
 
 def _setup_run(
     data: FederatedDataset,
@@ -492,18 +509,7 @@ class SyncScheduler:
         ckpt_dir = resolve_checkpoint_dir(checkpoint_every, checkpoint_dir, resume_from)
         su = _setup_run(data, cfg, init_fn, loss_fn, acc_fn, comm, pipeline, client_delay)
         comm, clock = su.comm, su.clock
-        state = RoundState(
-            global_params=su.g0,
-            local_params=su.loc0,
-            accuracy=jnp.zeros((data.n_clients,)),
-            select=jnp.ones((data.n_clients,), bool),
-            pms=jnp.full((data.n_clients,), su.pms0, jnp.int32),
-            rng=su.r_loop,
-            residual=su.residual0,
-            participation=jnp.zeros((data.n_clients,), jnp.int32),
-            loss=jnp.zeros((data.n_clients,), jnp.float32),
-            update_norm=jnp.zeros((data.n_clients,), jnp.float32),
-        )
+        state = su.initial_state()
         round_step = build_round_step(
             su.env, su.pipeline, cfg.execution, faults=faults if faulty else None
         )

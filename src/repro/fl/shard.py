@@ -54,7 +54,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ExecutionConfig
@@ -212,9 +211,9 @@ def build_sharded_round_step(
                      tree_lane_pspecs(loc_c, mesh) if stateful else rep,
                      tree_lane_pspecs(res_c, mesh),
                      lane)
-        new_g, new_local_c, new_res_c, unorm_c = shard_map(
+        new_g, new_local_c, new_res_c, unorm_c = jax.shard_map(
             cohort_compute, mesh=mesh, in_specs=in_specs,
-            out_specs=out_specs, check_rep=False,
+            out_specs=out_specs, check_vma=False,
         )(*args)
 
         # --- scatter: cohort results back into the (C, ...) server state ---

@@ -13,8 +13,9 @@ Kernels:
   quantize         — per-block absmax int8/int4 (de)quantization with
                      stochastic rounding (repro.comm wire-format hot path)
 
-This container is CPU-only: kernels are validated with interpret=True; on a
-real TPU set interpret=False (the default chooses by backend).
+The default ``interpret`` follows the backend: on a TPU the compiled
+kernels run (interpret=False); on the CPU, where the tests run, they run in
+interpret mode or, for the quantize pair, dispatch to the jnp reference.
 """
 
 from repro.kernels.flash_attention.ops import flash_attention

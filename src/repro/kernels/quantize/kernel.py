@@ -12,12 +12,20 @@ round-to-nearest. Noise is generated outside the kernel with jax.random so
 the kernel stays deterministic given its inputs and runs identically in
 interpret mode on CPU (pltpu.prng_* is TPU-compile only).
 
-Grid: (n_param_blocks,). BlockSpecs:
-  x      (P,)  -> (BP,)
-  noise  (P,)  -> (BP,)
-  q      (P,)  -> (BP,)  int8 out
-  scales (NB,) -> (1,)   one f32 scale per block (the codec's meta payload)
+Layout: the flat (P,) parameter axis is viewed as (NB, BP) — one row per
+quantization block — and the grid walks ROWS rows at a time, so every
+block is a 2-D (ROWS, BP) tile whose last dim is the whole row (TPU block
+shapes must match the array's trailing dims or the (8, 128) tiling; rank-1
+blocks of one scale each are refused by the TPU compiler). Rows are
+independent, so a ragged last row-block (NB % ROWS != 0) is safe: its
+out-of-range rows are never written back. BlockSpecs:
 
+  x      (NB, BP) -> (ROWS, BP)
+  noise  (NB, BP) -> (ROWS, BP)
+  q      (NB, BP) -> (ROWS, BP)  int8 out
+  scales (NB, 1)  -> (ROWS, 1)   one f32 scale per block (the codec's meta)
+
+The wire format is unchanged: ``(P,)`` int8 codes and ``(NB,)`` scales.
 int4 reuses the same int8 storage with qmax=7 — packing is accounted at the
 wire level (bits/8 bytes per element) by repro.comm, not materialised here.
 """
@@ -30,19 +38,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# rows per grid step: a multiple of the int8 (32, 128) tile, and at BP=512
+# a 512 KiB f32 input tile — x, noise and q double-buffered stay ~2.5 MiB
+# of VMEM
+ROWS = 256
+
+
+def _row_blocks(nb: int) -> tuple[int, int]:
+    """(rows per block, grid length) for NB quantization blocks."""
+    rows = min(nb, ROWS)
+    return rows, pl.cdiv(nb, rows)
+
 
 def _quant_kernel(x_ref, u_ref, q_ref, s_ref, *, qmax: float):
-    x = x_ref[...].astype(jnp.float32)          # (BP,)
-    u = u_ref[...].astype(jnp.float32)          # (BP,)
-    amax = jnp.max(jnp.abs(x))
-    scale = jnp.maximum(amax, 1e-12) / qmax
+    x = x_ref[...].astype(jnp.float32)          # (ROWS, BP)
+    u = u_ref[...].astype(jnp.float32)          # (ROWS, BP)
+    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    scale = jnp.maximum(amax, 1e-12) / qmax     # (ROWS, 1)
     q = jnp.clip(jnp.floor(x / scale + u), -qmax, qmax)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = jnp.full((1,), scale, jnp.float32)
+    s_ref[...] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
-    o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0]
+    o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...]
 
 
 def quantize_kernel(
@@ -56,24 +75,21 @@ def quantize_kernel(
     bp = min(block_p, p)
     assert p % bp == 0, "ops.py pads the param axis"
     nb = p // bp
+    rows, steps = _row_blocks(nb)
     qmax = float(2 ** (bits - 1) - 1)
-    return pl.pallas_call(
+    tile = pl.BlockSpec((rows, bp), lambda i: (i, 0))
+    q, scales = pl.pallas_call(
         functools.partial(_quant_kernel, qmax=qmax),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((bp,), lambda i: (i,)),
-            pl.BlockSpec((bp,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bp,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
+        grid=(steps,),
+        in_specs=[tile, tile],
+        out_specs=[tile, pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((p,), jnp.int8),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
+            jax.ShapeDtypeStruct((nb, bp), jnp.int8),
+            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x, noise)
+    )(x.reshape(nb, bp), noise.reshape(nb, bp))
+    return q.reshape(p), scales.reshape(nb)
 
 
 def dequantize_kernel(
@@ -84,15 +100,16 @@ def dequantize_kernel(
 ) -> jnp.ndarray:
     p = q.shape[0]
     bp = min(block_p, p)
-    assert p % bp == 0 and scales.shape[0] == p // bp
-    return pl.pallas_call(
+    nb = p // bp
+    assert p % bp == 0 and scales.shape[0] == nb
+    rows, steps = _row_blocks(nb)
+    tile = pl.BlockSpec((rows, bp), lambda i: (i, 0))
+    out = pl.pallas_call(
         _dequant_kernel,
-        grid=(p // bp,),
-        in_specs=[
-            pl.BlockSpec((bp,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((bp,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((p,), jnp.float32),
+        grid=(steps,),
+        in_specs=[tile, pl.BlockSpec((rows, 1), lambda i: (i, 0))],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((nb, bp), jnp.float32),
         interpret=interpret,
-    )(q, scales)
+    )(q.reshape(nb, bp), scales.reshape(nb, 1))
+    return out.reshape(p)

@@ -1,11 +1,15 @@
 """jit'd wrappers: flatten, pad to a whole number of blocks, run the kernel,
 slice back. Public entry points for repro.comm's QuantizeCodec.
 
-Off-TPU the wrapper dispatches to the vectorized jnp oracle (ref.py) instead
-of interpret-mode Pallas: interpret mode unrolls the grid at trace time, so
-a 300k-param leaf vmapped over 30 clients would explode compile times. The
-two paths compute the same math (the allclose sweep in tests/test_kernels.py
-style lives in tests/test_comm_codecs.py); on TPU the compiled kernel runs.
+On a TPU the compiled Pallas kernel runs (kernel.py). Anywhere else — the
+CPU backend, or a computation pinned to a CPU device with
+``jax.default_device`` — the wrapper dispatches to the vectorized jnp
+oracle (ref.py) instead of interpret-mode Pallas: interpret mode unrolls
+the grid at trace time, so a 300k-param leaf vmapped over 30 clients would
+explode compile times. The two paths compute the same math: the kernel is
+checked against ref.py in interpret mode in tests/test_comm_codecs.py,
+compiled for a described v5e in tests/test_tpu_compile.py, and run against
+ref.py on the chip by chip_smoke.py.
 """
 
 from __future__ import annotations
@@ -20,7 +24,11 @@ from repro.kernels.quantize.ref import dequantize_ref, quantize_ref
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True unless this computation lands on a TPU. A ``jax.default_device``
+    pin (a device or a platform name) overrides the default backend."""
+    pin = jax.config.jax_default_device
+    platform = getattr(pin, "platform", pin) if pin is not None else jax.default_backend()
+    return platform != "tpu"
 
 
 def quant_blocks(n: int, block_p: int = 512) -> tuple[int, int]:

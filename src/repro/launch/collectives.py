@@ -24,9 +24,11 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all", "col
 # e.g.  %all-reduce.5 = bf16[16,2048]{1,0} all-reduce(...)
 # Async pairs count once: the `-start` half carries the shapes (matched),
 # the `-done` half is bookkeeping (rejected — `-done` can't match
-# `(?:-start)?[\.\d]*\(`).
+# `(?:-start)?[\.\d]*\(`). TPU layouts nest one level of parentheses inside
+# a tuple shape (`(f32[256]{0:T(256)S(1)}, ...)`), so the tuple group
+# admits them.
 _OP_RE = re.compile(
-    r"=\s*(?:\(([^)]*)\)|(\w+)\[([\d,]*)\][^ ]*)\s+("
+    r"=\s*(?:\(((?:[^()]|\([^()]*\))*)\)|(\w+)\[([\d,]*)\][^ ]*)\s+("
     + "|".join(_COLLECTIVES)
     + r")(-start)?[\.\d]*\("
 )

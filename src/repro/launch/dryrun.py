@@ -21,14 +21,12 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", os.environ.get("JAX_CACHE_DIR", "/tmp/jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-
 from repro.configs import SHAPES, get_config, get_shape, list_archs
 from repro.launch import context as ctxmod
 from repro.launch.collectives import collective_breakdown_str, collective_bytes
 from repro.launch.hlo_analysis import analyze as hlo_analyze
-from repro.launch.mesh import HW, data_axes, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import V5E, chip_peaks, data_axes, make_production_mesh
 from repro.launch.sharding import batch_spec, cache_pspecs, tree_pspecs
 from repro.models.api import get_model, make_batch_specs
 from repro.optim import adamw
@@ -131,6 +129,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool = False, fl_shared: int 
 
     flops_dev = float(la["flops"])
     bytes_dev = float(la["bytes"])
+    peaks = chip_peaks(V5E)
 
     result = {
         **meta,
@@ -153,9 +152,9 @@ def run_one(arch: str, shape_name: str, multi_pod: bool = False, fl_shared: int 
             "generated_code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
         },
         # roofline terms (seconds) — per-device quantities over per-chip rates
-        "t_compute": flops_dev / HW["peak_flops_bf16"],
-        "t_memory": bytes_dev / HW["hbm_bw"],
-        "t_collective": float(la["collective_bytes"]) / HW["ici_bw"],
+        "t_compute": flops_dev / peaks["peak_flops_bf16"],
+        "t_memory": bytes_dev / peaks["hbm_bw"],
+        "t_collective": float(la["collective_bytes"]) / peaks["ici_bw"],
     }
     terms = {k: result[k] for k in ("t_compute", "t_memory", "t_collective")}
     result["bottleneck"] = max(terms, key=terms.get)
@@ -186,6 +185,7 @@ def main():
                     help="§Perf: sequence-parallel residual stream (train)")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
+    enable_compile_cache()
 
     os.makedirs(args.out, exist_ok=True)
     combos = []

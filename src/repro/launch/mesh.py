@@ -9,6 +9,7 @@ touches jax device state (the dry-run sets XLA_FLAGS before first init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -35,6 +36,11 @@ def make_cohort_mesh(n_devices: int | None = None):
     takes a prefix (dev/test runs force host devices via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` in a fresh
     process — see tests/_subproc.py).
+
+    The axis is ``Auto``: the scatter after the cohort ``shard_map`` writes
+    lane-sharded results into replicated slabs and leaves the layout to the
+    partitioner, which ``make_mesh``'s default ``Explicit`` axes reject with
+    a ``ShardingTypeError``.
     """
     devices = jax.devices()
     n = len(devices) if not n_devices else int(n_devices)
@@ -46,17 +52,40 @@ def make_cohort_mesh(n_devices: int | None = None):
             f"{len(devices)} visible (force host devices in a subprocess "
             f"via XLA_FLAGS=--xla_force_host_platform_device_count={n})"
         )
-    return jax.make_mesh((n,), ("cohort",), devices=devices[:n])
+    return jax.make_mesh(
+        (n,), ("cohort",), devices=devices[:n], axis_types=(AxisType.Auto,)
+    )
 
 
 def data_axes(multi_pod: bool = False):
     return ("pod", "data") if multi_pod else ("data",)
 
 
-HW = {
-    # TPU v5e per-chip constants (assignment-specified)
-    "peak_flops_bf16": 197e12,   # FLOP/s
-    "hbm_bw": 819e9,             # B/s
-    "ici_bw": 50e9,              # B/s per link
-    "chips_per_pod": 256,
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (system architecture): 197 TFLOP/s
+# bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,   # FLOP/s
+        "peak_ops_int8": 393e12,     # OP/s
+        "hbm_bytes": 16e9,           # B
+        "hbm_bw": 819e9,             # B/s
+        "ici_bw": 50e9,              # B/s per link
+    },
 }
+
+# the chip the production-mesh dry-run compiles for
+V5E = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None

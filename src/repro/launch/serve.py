@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model, make_concrete_batch
 from repro.serve import (
     ContinuousBatcher,
@@ -54,6 +55,7 @@ def main():
     ap.add_argument("--record", default=None, metavar="DIR",
                     help="write a serve record (manifest/requests/trace) here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     bundle = get_model(cfg)

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.checkpoint import save_pytree
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model, make_concrete_batch
 from repro.optim import adamw, chain, clip_by_global_norm, cosine_schedule
 
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -61,11 +61,10 @@ class Profiler:
     # -- lifecycle ---------------------------------------------------------
     def start(self):
         if self._jax_trace_dir:
-            try:
-                jax.profiler.start_trace(self._jax_trace_dir)
-                self._jax_tracing = True
-            except Exception:  # backend without profiler support: degrade
-                self._jax_tracing = False
+            # a trace that was asked for and cannot start is an error: the
+            # run must not silently carry on without it
+            jax.profiler.start_trace(self._jax_trace_dir)
+            self._jax_tracing = True
 
     def stop(self):
         if self._jax_tracing:

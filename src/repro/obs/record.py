@@ -115,6 +115,7 @@ def environment_snapshot() -> dict:
     return {
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
+        "device_kind": jax.devices()[0].device_kind,
         "devices": [str(d) for d in jax.devices()],
         "python": sys.version.split()[0],
         "platform": platform.platform(),

@@ -171,18 +171,7 @@ def fit_servable(
     from repro.fl.sched import _setup_run
 
     su = _setup_run(data, cfg, None, mlp_loss, mlp_accuracy, None, None, None)
-    state = RoundState(
-        global_params=su.g0,
-        local_params=su.loc0,
-        accuracy=jnp.zeros((data.n_clients,)),
-        select=jnp.ones((data.n_clients,), bool),
-        pms=jnp.full((data.n_clients,), su.pms0, jnp.int32),
-        rng=su.r_loop,
-        residual=su.residual0,
-        participation=jnp.zeros((data.n_clients,), jnp.int32),
-        loss=jnp.zeros((data.n_clients,), jnp.float32),
-        update_norm=jnp.zeros((data.n_clients,), jnp.float32),
-    )
+    state = su.initial_state()
     step = jax.jit(build_round_step(su.env, su.pipeline, cfg.execution))
     for t in range(cfg.rounds):
         state, out = step(state, jnp.asarray(t))

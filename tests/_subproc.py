@@ -1,6 +1,6 @@
 """Run a snippet in a fresh interpreter with forced host devices.
 
-conftest.py line 4 forbids setting ``--xla_force_host_platform_device_count``
+conftest.py forbids setting ``--xla_force_host_platform_device_count``
 in-process (smoke tests and benches must see 1 device; jax locks the device
 count at first init), so every multi-device test re-execs its body here:
 a subprocess gets ``XLA_FLAGS=--xla_force_host_platform_device_count=N``

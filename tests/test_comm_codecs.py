@@ -26,7 +26,9 @@ from repro.kernels.quantize.ref import dequantize_ref, quantize_ref
 
 from repro.kernels.quantize.kernel import dequantize_kernel, quantize_kernel
 
-Q_SHAPES = [8, 512, 1024, 4096]
+# 281 blocks of 512 (the har-mlp input layer, padded) spans two row-blocks
+# of the kernel grid, the second one ragged
+Q_SHAPES = [8, 512, 1024, 4096, 281 * 512]
 
 
 @pytest.mark.parametrize("n", Q_SHAPES)

@@ -16,7 +16,7 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from _subproc import run_forced
 from repro.launch.collectives import collective_bytes
-from repro.launch.mesh import data_axes, make_cohort_mesh
+from repro.launch.mesh import chip_peaks, data_axes, make_cohort_mesh
 from repro.launch.sharding import (
     batch_spec,
     lane_spec,
@@ -25,9 +25,9 @@ from repro.launch.sharding import (
     tree_pspecs,
 )
 
-PROD = AbstractMesh((("data", 16), ("model", 16)))
-PODS = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-COHORT4 = AbstractMesh((("cohort", 4),))
+PROD = AbstractMesh((16, 16), ("data", "model"))
+PODS = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+COHORT4 = AbstractMesh((4,), ("cohort",))
 
 
 # ---------------------------------------------------------------------------
@@ -142,6 +142,12 @@ def test_tree_lane_pspecs_and_eval_shape():
 # ---------------------------------------------------------------------------
 
 
+def test_chip_peaks_known_kind_and_unknown_raises():
+    assert chip_peaks("TPU v5 lite")["peak_flops_bf16"] == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
+
+
 def test_make_cohort_mesh_single_device():
     # in-process the container sees exactly one device (conftest guards this)
     m = make_cohort_mesh()
@@ -220,6 +226,20 @@ def test_collective_bytes_async_counts_start_once():
     assert stats["all-reduce"] == 16 * 2048 * 4        # not doubled
     assert stats["all-gather"] == 64 * 128 * 4          # dest, not src+dest
     assert stats["collective-permute"] == 256 * 4
+
+
+# TPU-compiled HLO: tiled layouts put parentheses inside a tuple shape
+_TPU_TUPLE_HLO = """
+  %all-reduce.19 = (f32[256]{0:T(256)S(1)}, f32[]{:T(128)}, f32[561,256]{1,0:T(8,128)S(1)}) all-reduce(%a, %b, %c), channel_id=1, to_apply=%add
+  %collective-permute-start = (s32[16]{0:T(128)}, s32[16]{0:T(128)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%x), channel_id=2, source_target_pairs={{0,1}}
+"""
+
+
+def test_collective_bytes_tpu_tiled_tuple_layouts():
+    stats = collective_bytes(_TPU_TUPLE_HLO)
+    assert stats["count"] == 2
+    assert stats["all-reduce"] == (256 + 1 + 561 * 256) * 4
+    assert stats["collective-permute"] == 16 * 4
 
 
 def test_collective_bytes_mixed_and_empty():

@@ -6,12 +6,14 @@ golden config), and the manifest/run-log plumbing."""
 import json
 import os
 
+import jax
 import numpy as np
 import pytest
 
 from repro.data import make_federated_classification
 from repro.fl import FLConfig, run_federated
 from repro.obs import (
+    Profiler,
     RunRecorder,
     TraceBuilder,
     environment_snapshot,
@@ -264,4 +266,16 @@ def test_profile_smoke(small_ds, tmp_path, mode):
 def test_environment_snapshot_shape():
     env = environment_snapshot()
     assert env["backend"] and env["device_count"] >= 1
+    assert env["device_kind"] == jax.devices()[0].device_kind
     assert env["packages"]["jax"]
+
+
+def test_profiler_raises_when_its_trace_cannot_start(tmp_path):
+    """A jax.profiler trace that was asked for and cannot start (here: one
+    is already running) is an error, not a run without the trace."""
+    jax.profiler.start_trace(str(tmp_path / "outer"))
+    try:
+        with pytest.raises(RuntimeError):
+            Profiler(jax_trace_dir=str(tmp_path / "inner")).start()
+    finally:
+        jax.profiler.stop_trace()

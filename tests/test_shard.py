@@ -6,7 +6,7 @@ In-process tests run at D=1 (the container's single default device) — the
 sharded step over a 1-device mesh must be bit-identical to the unsharded
 step on every committed golden. Multi-device tests re-exec in a subprocess
 with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-(tests/_subproc.py; conftest.py:4 forbids forcing devices in-process).
+(tests/_subproc.py; conftest.py forbids forcing devices in-process).
 
 Parity contract at D>1: every per-lane number is bit-identical (lanes are
 computed by the same code on the same values, just on different devices) —
@@ -68,11 +68,11 @@ def test_cohort_lanes_must_divide_mesh(small_ds):
     env = build_env(small_ds, cfg.seed)
     pipe = pipeline_from_config(cfg)
     # 8 lanes over a 3-way cohort axis: rejected before any compute
-    mesh3 = AbstractMesh((("cohort", 3),))
+    mesh3 = AbstractMesh((3,), ("cohort",))
     with pytest.raises(ValueError, match="must divide"):
         build_sharded_round_step(env, pipe, cfg.execution, mesh=mesh3)
     # a mesh without the cohort axis is rejected too
-    meshx = AbstractMesh((("data", 2),))
+    meshx = AbstractMesh((2,), ("data",))
     with pytest.raises(ValueError, match="cohort"):
         build_sharded_round_step(env, pipe, cfg.execution, mesh=meshx)
 
@@ -242,7 +242,6 @@ _DONATION_BODY = """
 import numpy as np, jax, jax.numpy as jnp
 from repro.data.synthetic import make_federated_classification
 from repro.fl import FLConfig, api
-from repro.fl.api import RoundState
 from repro.fl.sched import _setup_run
 from repro.launch.collectives import collective_bytes
 from repro.models.mlp import mlp_accuracy, mlp_loss
@@ -258,14 +257,7 @@ step = api.build_round_step(su.env, su.pipeline, cfg.execution)
 assert dict(step.mesh.shape) == {"cohort": 2}
 assert step.lanes_per_device == 4
 
-c = ds.n_clients
-state = RoundState(
-    global_params=su.g0, local_params=su.loc0,
-    accuracy=jnp.zeros((c,)), select=jnp.ones((c,), bool),
-    pms=jnp.full((c,), su.pms0, jnp.int32), rng=su.r_loop,
-    residual=su.residual0, participation=jnp.zeros((c,), jnp.int32),
-    loss=jnp.zeros((c,), jnp.float32), update_norm=jnp.zeros((c,), jnp.float32),
-)
+state = su.initial_state()
 chunk = api.build_chunk_step(step, 2)
 ts = jnp.arange(2, dtype=jnp.int32)
 # per-device collective traffic is visible in the optimized SPMD HLO: the
