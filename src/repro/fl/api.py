@@ -489,6 +489,7 @@ def build_round_step(
     max_norm = float(faults.max_update_norm) if faulty else 0.0
     corrupt_scale = float(faults.corrupt_scale) if faulty else 0.0
 
+    @phases.scoped("fl.round")
     def _round_body(state: RoundState, t: jnp.ndarray, alive, corrupt):
         g = state.global_params
         n_layers = len(g)
@@ -504,39 +505,40 @@ def build_round_step(
         # crashed / past-deadline clients (fault mode) never enter the
         # cohort: they trained nothing the server sees, pay no wire, and
         # their lanes backfill from the remaining selected clients
-        select_in = state.select if alive is None else state.select & alive
-        idx = cohort_indices(select_in, cohort_k)
-        cmask = jnp.take(select_in, idx)
-        # executed = selected AND inside the cohort bound; when the strategy
-        # selects more than K clients the overflow neither trains nor pays
-        # wire (at K = C executed == select exactly)
-        executed = (
-            jnp.zeros(state.select.shape, bool).at[idx].set(cmask)
-        )
-        # participation defaults to None on hand-built states (the exported
-        # RoundState mirrors the old _RoundState shape) — treat as zeros
-        prev_part = (
-            state.participation
-            if state.participation is not None
-            else jnp.zeros(state.select.shape, jnp.int32)
-        )
-        participation = prev_part + executed.astype(jnp.int32)
-        cenv = env.take(idx)
-        cctx = phases.RoundContext(
-            t=t,
-            global_params=g,
-            local_params=tree_take(state.local_params, idx) if stateful else None,
-            select=cmask,
-            pms=jnp.take(state.pms, idx),
-            share=jnp.take(share, idx, axis=0),
-            residual=tree_take(state.residual, idx),
-            participation=jnp.take(participation, idx),
-            cohort_idx=idx,
-            cohort_mask=cmask,
-            rng_fit=r_fit,
-            rng_codec=r_codec,
-            rng_sel=r_sel,
-        )
+        with jax.named_scope("fl.gather"):
+            select_in = state.select if alive is None else state.select & alive
+            idx = cohort_indices(select_in, cohort_k)
+            cmask = jnp.take(select_in, idx)
+            # executed = selected AND inside the cohort bound; when the strategy
+            # selects more than K clients the overflow neither trains nor pays
+            # wire (at K = C executed == select exactly)
+            executed = (
+                jnp.zeros(state.select.shape, bool).at[idx].set(cmask)
+            )
+            # participation defaults to None on hand-built states (the exported
+            # RoundState mirrors the old _RoundState shape) — treat as zeros
+            prev_part = (
+                state.participation
+                if state.participation is not None
+                else jnp.zeros(state.select.shape, jnp.int32)
+            )
+            participation = prev_part + executed.astype(jnp.int32)
+            cenv = env.take(idx)
+            cctx = phases.RoundContext(
+                t=t,
+                global_params=g,
+                local_params=tree_take(state.local_params, idx) if stateful else None,
+                select=cmask,
+                pms=jnp.take(state.pms, idx),
+                share=jnp.take(share, idx, axis=0),
+                residual=tree_take(state.residual, idx),
+                participation=jnp.take(participation, idx),
+                cohort_idx=idx,
+                cohort_mask=cmask,
+                rng_fit=r_fit,
+                rng_codec=r_codec,
+                rng_sel=r_sel,
+            )
 
         # --- personalization: build each cohort lane's training model ---
         cctx = cctx._replace(train_model=pipeline.personalizer.train_model(cctx, cenv))
@@ -552,16 +554,17 @@ def build_round_step(
             cctx = cctx._replace(
                 trained=apply_corruption(cctx.trained, kinds_k, corrupt_scale)
             )
-        if stateful:
-            cctx = cctx._replace(
-                new_local=jax.tree.map(
-                    lambda new, old: jnp.where(
-                        cmask.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
-                    ),
-                    cctx.trained,
-                    pipeline.personalizer.local_fallback(cctx, cenv),
+        with jax.named_scope("fl.personalize"):
+            if stateful:
+                cctx = cctx._replace(
+                    new_local=jax.tree.map(
+                        lambda new, old: jnp.where(
+                            cmask.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
+                        ),
+                        cctx.trained,
+                        pipeline.personalizer.local_fallback(cctx, cenv),
+                    )
                 )
-            )
         # --- wire codec: compress each cohort lane's shared delta (uplink) ---
         local_before = cctx.local_params if stateful else None
         res_before = cctx.residual
@@ -575,23 +578,25 @@ def build_round_step(
             if state.update_norm is not None
             else jnp.zeros(state.select.shape, jnp.float32)
         )
-        ok, n_rejected = finite_update_guard(cmask, cctx.update_norm, max_norm)
-        cctx = cctx._replace(
-            select=cmask & ok,
-            residual=_tree_where(ok, cctx.residual, res_before),
-            update_norm=jnp.where(ok, cctx.update_norm, jnp.take(prev_norm, idx)),
-        )
-        if stateful:
-            cctx = cctx._replace(new_local=_tree_where(ok, cctx.new_local, local_before))
+        with jax.named_scope("fl.transmit"):
+            ok, n_rejected = finite_update_guard(cmask, cctx.update_norm, max_norm)
+            cctx = cctx._replace(
+                select=cmask & ok,
+                residual=_tree_where(ok, cctx.residual, res_before),
+                update_norm=jnp.where(ok, cctx.update_norm, jnp.take(prev_norm, idx)),
+            )
+            if stateful:
+                cctx = cctx._replace(new_local=_tree_where(ok, cctx.new_local, local_before))
         # --- aggregation of shared pieces (Eq. 1, masked/partial), K lanes ---
         cctx = pipeline.aggregator.aggregate(cctx, cenv)
 
         # --- scatter: cohort results back into the (C, ...) server state ---
-        new_local = (
-            tree_scatter(state.local_params, idx, cctx.new_local) if stateful else None
-        )
-        new_residual = tree_scatter(state.residual, idx, cctx.residual)
-        update_norm = prev_norm.at[idx].set(cctx.update_norm)
+        with jax.named_scope("fl.scatter"):
+            new_local = (
+                tree_scatter(state.local_params, idx, cctx.new_local) if stateful else None
+            )
+            new_residual = tree_scatter(state.residual, idx, cctx.residual)
+            update_norm = prev_norm.at[idx].set(cctx.update_norm)
         wire_prospective, wire_paid = pipeline.transmit.wire_costs(
             g, share, executed
         )
@@ -713,6 +718,7 @@ def build_chunk_step(round_step, length: int):
         # same numerics contract a per-round jit dispatch provides
         return jax.lax.optimization_barrier((state, out))
 
+    @phases.scoped("fl.chunk")
     def chunk_step(state: RoundState, ts: jnp.ndarray):
         return jax.lax.scan(body, state, ts, unroll=length)
 

@@ -55,6 +55,7 @@ so the chunk can stack it to ``(T_chunk, ...)`` leaves fetched in one
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -70,6 +71,48 @@ from repro.core import (
 )
 from repro.core.aggregation import staleness_weighted_merge
 from repro.core.selection import ClientObservations, SelectionStrategy
+
+# Device scopes. Every op a round emits carries, in its HLO ``op_name``
+# metadata, the body it runs in (``fl.round``, ``fl.event`` for the async
+# step, ``fl.chunk`` around the fused scan) and the phase inside it
+# (``fl.personalize``, ``fl.train``, ``fl.transmit``, ``fl.aggregate``,
+# ``fl.eval``, ``fl.select``, plus ``fl.gather``/``fl.scatter`` at the
+# steps' cohort gather and scatter sites). The steps also scope their own
+# glue: the lanes' new local models (trained where selected, the
+# personalizer's fallback elsewhere) as ``fl.personalize``, and the finite
+# guard that rejects a non-finite uplink and reverts its lane as
+# ``fl.transmit``. A device trace then attributes each op's time to a
+# phase. Scopes are trace-time metadata only: they change no number the
+# step computes.
+
+
+def scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        run.fl_scope = name
+        return run
+
+    return wrap
+
+
+class _Phase:
+    """Base of the phase classes: each method named in ``_SCOPED`` that a
+    class (or any subclass) defines runs under its ``fl.<phase>`` scope."""
+
+    _SCOPED: dict[str, str] = {}
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        for method, phase in cls._SCOPED.items():
+            fn = cls.__dict__.get(method)
+            if callable(fn) and not hasattr(fn, "fl_scope"):
+                setattr(cls, method, scoped(f"fl.{phase}")(fn))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,7 +264,7 @@ def _client_global(ctx: RoundContext, env: RoundEnv):
 # ---------------------------------------------------------------------------
 
 
-class Personalizer:
+class Personalizer(_Phase):
     """Decides what model each client trains and is evaluated on.
 
     ``stateful`` declares whether the personalizer reads/writes per-client
@@ -231,6 +274,8 @@ class Personalizer:
     """
 
     stateful: bool = True
+    _SCOPED = {"train_model": "personalize", "eval_model": "personalize",
+               "local_fallback": "personalize"}
 
     def train_model(self, ctx: RoundContext, env: RoundEnv):
         raise NotImplementedError
@@ -341,8 +386,10 @@ def _batched(x, y, m, batch_size: int, remainder: str = "drop"):
     )
 
 
-class LocalTrainer:
+class LocalTrainer(_Phase):
     """Produces ``ctx.trained`` from ``ctx.train_model`` (Algorithm 2)."""
+
+    _SCOPED = {"fit": "train"}
 
     def fit(self, ctx: RoundContext, env: RoundEnv) -> RoundContext:
         raise NotImplementedError
@@ -409,7 +456,7 @@ def _client_sq_norms(stacked, reference):
 
 
 @dataclasses.dataclass(frozen=True)
-class TransmitPhase:
+class TransmitPhase(_Phase):
     """Wire-codec phase: the uplink every selected client's shared delta
     takes to the server.
 
@@ -429,6 +476,7 @@ class TransmitPhase:
     """
 
     codec: Codec
+    _SCOPED = {"transmit": "transmit", "wire_costs": "transmit"}
 
     @property
     def lossy(self) -> bool:
@@ -537,7 +585,7 @@ class TransmitPhase:
 # ---------------------------------------------------------------------------
 
 
-class Aggregator:
+class Aggregator(_Phase):
     """Reduces the lane axis into the new global model.
 
     All three implementations express the reduction as weighted partial
@@ -559,6 +607,7 @@ class Aggregator:
 
     edge_groups = 0   # subclasses declare the dataclass field
     axis_name = None  # subclasses declare the dataclass field (kept last)
+    _SCOPED = {"aggregate": "aggregate"}
 
     def _edges(self, ctx: RoundContext, env: RoundEnv):
         """``(edge_ids, n_edges)`` for the current lanes, or ``(None, 0)``
@@ -714,7 +763,9 @@ class StalenessAggregator(Aggregator):
 # ---------------------------------------------------------------------------
 
 
-class Evaluator:
+class Evaluator(_Phase):
+    _SCOPED = {"evaluate": "eval"}
+
     def evaluate(self, ctx: RoundContext, env: RoundEnv, model_fn=None) -> RoundContext:
         raise NotImplementedError
 
@@ -776,11 +827,12 @@ class DistributedEvaluator(Evaluator):
 
 
 @dataclasses.dataclass(frozen=True)
-class SelectorPhase:
+class SelectorPhase(_Phase):
     """Wraps a SelectionStrategy; assembles the full ClientObservations
     (including the codec-phase cost signals) and picks next round's cohort."""
 
     strategy: SelectionStrategy
+    _SCOPED = {"select": "select"}
 
     def select(self, ctx: RoundContext, env: RoundEnv) -> RoundContext:
         obs = ClientObservations(
@@ -800,7 +852,9 @@ class SelectorPhase:
 # ---------------------------------------------------------------------------
 
 
-class LayerPolicy:
+class LayerPolicy(_Phase):
+    _SCOPED = {"next_pms": "select"}
+
     def next_pms(self, ctx: RoundContext, env: RoundEnv, n_layers: int):
         raise NotImplementedError
 

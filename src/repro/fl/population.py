@@ -331,6 +331,7 @@ def _build_cohort_step(pipeline: RoundPipeline, n_layers: int, k: int,
     max_norm = float(faults.max_update_norm) if faulty else 0.0
     corrupt_scale = float(faults.corrupt_scale) if faulty else 0.0
 
+    @phases.scoped("fl.round")
     def _cohort_body(g, rng, t, idx, cmask, pms_k, participation_k,
                      local_k, residual_k, data_k, n_samples_k, delay_k,
                      prev_un_k, corrupt_k):
@@ -371,29 +372,31 @@ def _build_cohort_step(pipeline: RoundPipeline, n_layers: int, k: int,
             cctx = cctx._replace(
                 trained=apply_corruption(cctx.trained, kinds_k, corrupt_scale)
             )
-        if stateful:
-            cctx = cctx._replace(
-                new_local=jax.tree.map(
-                    lambda new, old: jnp.where(
-                        cmask.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
-                    ),
-                    cctx.trained,
-                    pipeline.personalizer.local_fallback(cctx, cenv),
+        with jax.named_scope("fl.personalize"):
+            if stateful:
+                cctx = cctx._replace(
+                    new_local=jax.tree.map(
+                        lambda new, old: jnp.where(
+                            cmask.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
+                        ),
+                        cctx.trained,
+                        pipeline.personalizer.local_fallback(cctx, cenv),
+                    )
                 )
-            )
         local_before = cctx.local_params if stateful else None
         res_before = cctx.residual
         cctx = pipeline.transmit.transmit(cctx, cenv)
         # finite-delta guard (always on) — same expressions as the device
         # round step, so all-finite rounds are bit-identical to it
-        ok, n_rejected = finite_update_guard(cmask, cctx.update_norm, max_norm)
-        cctx = cctx._replace(
-            select=cmask & ok,
-            residual=_tree_where(ok, cctx.residual, res_before),
-            update_norm=jnp.where(ok, cctx.update_norm, prev_un_k),
-        )
-        if stateful:
-            cctx = cctx._replace(new_local=_tree_where(ok, cctx.new_local, local_before))
+        with jax.named_scope("fl.transmit"):
+            ok, n_rejected = finite_update_guard(cmask, cctx.update_norm, max_norm)
+            cctx = cctx._replace(
+                select=cmask & ok,
+                residual=_tree_where(ok, cctx.residual, res_before),
+                update_norm=jnp.where(ok, cctx.update_norm, prev_un_k),
+            )
+            if stateful:
+                cctx = cctx._replace(new_local=_tree_where(ok, cctx.new_local, local_before))
         cctx = pipeline.aggregator.aggregate(cctx, cenv)
         return (cctx.new_global, cctx.new_local, cctx.residual,
                 cctx.update_norm, n_rejected, rng, r_sel)
@@ -428,6 +431,8 @@ def _build_eval_step(pipeline: RoundPipeline, n_layers: int, population: int,
     can move the masked-mean division by 1 ulp; use ``eval_chunk=0`` when
     exact bits matter and the test slab fits)."""
 
+    @phases.scoped("fl.round")
+    @phases.scoped("fl.eval")
     def eval_step(new_global, local_rows, pms_rows, x_te, y_te, m_te):
         env_c = phases.RoundEnv(
             x_tr=None, y_tr=None, m_tr=None, x_te=x_te, y_te=y_te, m_te=m_te,
@@ -465,6 +470,8 @@ def _build_eval_full(pipeline: RoundPipeline, n_layers: int, data, c: int,
         loss_fn=loss_fn, acc_fn=acc_fn, population=c,
     )
 
+    @phases.scoped("fl.round")
+    @phases.scoped("fl.eval")
     def eval_full(new_global, local_full, pms_lane):
         ctx = phases.RoundContext(
             new_global=new_global,
@@ -488,6 +495,7 @@ def _build_pop_step(pipeline: RoundPipeline, n_layers: int, population: int,
     lw_j = jnp.asarray(lw, jnp.float32)
     sizes_j = jnp.asarray(sizes, jnp.int32)
 
+    @phases.scoped("fl.round")
     def pop_step(t, r_sel, pms, executed, accuracy, loss, update_norm,
                  participation, n_samples, delay):
         share = layer_share_mask(n_layers, pms)
@@ -897,6 +905,7 @@ def _build_async_host_step(pipeline: RoundPipeline, n_layers: int, m: int,
     max_norm = float(faults.max_update_norm) if faulty else 0.0
     corrupt_scale = float(faults.corrupt_scale) if faulty else 0.0
 
+    @phases.scoped("fl.event")
     def _step_body(g, slot_params, rng, t, cids, slot_pms, land, staleness,
                    local_m, residual_m, participation_m, data_m, n_samples_m,
                    delay_m, prev_un_m, corrupt_m):
@@ -939,30 +948,32 @@ def _build_async_host_step(pipeline: RoundPipeline, n_layers: int, m: int,
             cctx = cctx._replace(
                 trained=apply_corruption(cctx.trained, kinds_m, corrupt_scale)
             )
-        if stateful:
-            cctx = cctx._replace(
-                new_local=jax.tree.map(
-                    lambda new, old: jnp.where(
-                        land.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
-                    ),
-                    cctx.trained,
-                    pipeline.personalizer.local_fallback(cctx, menv),
+        with jax.named_scope("fl.personalize"):
+            if stateful:
+                cctx = cctx._replace(
+                    new_local=jax.tree.map(
+                        lambda new, old: jnp.where(
+                            land.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
+                        ),
+                        cctx.trained,
+                        pipeline.personalizer.local_fallback(cctx, menv),
+                    )
                 )
-            )
         local_before = cctx.local_params if stateful else None
         res_before = cctx.residual
         cctx = pipeline.transmit.transmit(cctx, menv)
         # finite-delta guard (always on) — same expressions as the device
         # async step, so all-finite events are bit-identical to it
-        ok, n_rejected = finite_update_guard(land, cctx.update_norm, max_norm)
-        cctx = cctx._replace(
-            select=land & ok,
-            update_norm=jnp.where(ok, cctx.update_norm, prev_un_m),
-        )
-        if res_before is not None:
-            cctx = cctx._replace(residual=_tree_where(ok, cctx.residual, res_before))
-        if stateful:
-            cctx = cctx._replace(new_local=_tree_where(ok, cctx.new_local, local_before))
+        with jax.named_scope("fl.transmit"):
+            ok, n_rejected = finite_update_guard(land, cctx.update_norm, max_norm)
+            cctx = cctx._replace(
+                select=land & ok,
+                update_norm=jnp.where(ok, cctx.update_norm, prev_un_m),
+            )
+            if res_before is not None:
+                cctx = cctx._replace(residual=_tree_where(ok, cctx.residual, res_before))
+            if stateful:
+                cctx = cctx._replace(new_local=_tree_where(ok, cctx.new_local, local_before))
         cctx = pipeline.aggregator.aggregate(cctx, menv)
         land_f = land.astype(jnp.float32)
         n_land = jnp.maximum(jnp.sum(land_f), 1.0)
@@ -1004,6 +1015,7 @@ def _build_async_pop_step(pipeline: RoundPipeline, n_layers: int,
     c = population
     lw_j = jnp.asarray(lw, jnp.float32)
 
+    @phases.scoped("fl.event")
     def pop_step(t, r_sel, client_pms, land_c, accuracy, loss, update_norm,
                  participation, n_samples, delay, idle_now, cids, land,
                  active, slot_pms, force):
@@ -1047,6 +1059,8 @@ def _build_async_pop_step(pipeline: RoundPipeline, n_layers: int,
 
 
 def _build_slot_update(pipeline: RoundPipeline):
+    @phases.scoped("fl.event")
+    @phases.scoped("fl.scatter")
     def upd(slot_params, new_global, dispatched):
         return jax.tree.map(
             lambda s, gl: jnp.where(

@@ -616,68 +616,70 @@ class SyncScheduler:
                     outs = jax.device_get(outs)  # the ONE host sync this chunk pays
             if prof is not None:
                 prof.end_chunk()
-            acc = np.asarray(outs["acc"])                            # (n, C)
-            sel = np.asarray(outs["selected"])                       # (n, C)
-            pms = np.asarray(outs["pms"])                            # (n, C)
-            wire = np.asarray(outs["wire_per_client"], np.float64)   # (n, C)
-            # simulated round times, whole chunk at once: slowest selected
-            # client per round — codec-compressed uplink, uncompressed
-            # float32 downlink (the server broadcasts the exact global
-            # model); the prefix lookup + FLOPs + round_times are a single
-            # numpy pass over (n, C), no per-round numpy<->jnp churn
-            per_client_params = clock.shared_params(pms)             # (n, C)
-            if n_edges >= 1:
-                e_bytes = edge_hop_bytes(sel, pms, layer_sizes, edge_ids, n_edges)
-                edge_hist.append(e_bytes)
-                rt = comm.edge_round_times(
-                    wire, clock.round_flops(pms), sel, edge_ids, e_bytes,
-                    rx_bytes=per_client_params * float(BYTES_PER_PARAM),
-                    delay=delay,
+            with phase_timer(prof, "account"):
+                acc = np.asarray(outs["acc"])                            # (n, C)
+                sel = np.asarray(outs["selected"])                       # (n, C)
+                pms = np.asarray(outs["pms"])                            # (n, C)
+                wire = np.asarray(outs["wire_per_client"], np.float64)   # (n, C)
+                # simulated round times, whole chunk at once: slowest selected
+                # client per round — codec-compressed uplink, uncompressed
+                # float32 downlink (the server broadcasts the exact global
+                # model); the prefix lookup + FLOPs + round_times are a single
+                # numpy pass over (n, C), no per-round numpy<->jnp churn
+                per_client_params = clock.shared_params(pms)             # (n, C)
+                if n_edges >= 1:
+                    e_bytes = edge_hop_bytes(sel, pms, layer_sizes, edge_ids, n_edges)
+                    edge_hist.append(e_bytes)
+                    rt = comm.edge_round_times(
+                        wire, clock.round_flops(pms), sel, edge_ids, e_bytes,
+                        rx_bytes=per_client_params * float(BYTES_PER_PARAM),
+                        delay=delay,
+                    )
+                else:
+                    rt = comm.round_times(
+                        wire, clock.round_flops(pms), sel,
+                        rx_bytes=per_client_params * float(BYTES_PER_PARAM),
+                        # None on the homogeneous default: no delay lane to pay
+                        delay=delay,
+                    )
+                n_dropped = None
+                if faulty:
+                    # the server waits on everyone it dispatched, but only up
+                    # to the deadline: round time = slowest *dispatched* client
+                    # at its fault-slowed duration, deadline-capped
+                    wait = dur_t[sel_pre]
+                    rt_t = float(wait.max()) if wait.size else 0.0
+                    if faults.deadline_s > 0.0:
+                        rt_t = min(rt_t, faults.deadline_s)
+                    rt = np.asarray([rt_t + comm.server_latency_s], np.float64)
+                    n_dropped = int((sel_pre & ~alive_np).sum())
+                rej = (
+                    np.asarray(outs["rejected"], np.int64)
+                    if "rejected" in outs
+                    else np.zeros((n,), np.int64)  # sharded step: no guard leaf
                 )
-            else:
-                rt = comm.round_times(
-                    wire, clock.round_flops(pms), sel,
-                    rx_bytes=per_client_params * float(BYTES_PER_PARAM),
-                    # None on the homogeneous default: no delay lane to pay
-                    delay=delay,
-                )
-            n_dropped = None
-            if faulty:
-                # the server waits on everyone it dispatched, but only up
-                # to the deadline: round time = slowest *dispatched* client
-                # at its fault-slowed duration, deadline-capped
-                wait = dur_t[sel_pre]
-                rt_t = float(wait.max()) if wait.size else 0.0
-                if faults.deadline_s > 0.0:
-                    rt_t = min(rt_t, faults.deadline_s)
-                rt = np.asarray([rt_t + comm.server_latency_s], np.float64)
-                n_dropped = int((sel_pre & ~alive_np).sum())
-            rej = (
-                np.asarray(outs["rejected"], np.int64)
-                if "rejected" in outs
-                else np.zeros((n,), np.int64)  # sharded step: no guard leaf
-            )
-            rejected_hist.append(rej)
-            times.append(rt)
-            accs.append(acc)
-            sel_hist.append(sel)
-            pms_hist.append(pms)
-            tx_hist.append(np.asarray(outs["tx_params"], np.float64))
-            wire_hist.append(wire.sum(axis=1))
-            if recorder is not None:
-                # one vectorized append per chunk, straight off the stacked
-                # out leaves the device_get above already fetched
-                recorder.on_sync_chunk(
-                    t0=t0, acc=acc, sel=sel, pms=pms, wire=wire,
-                    tx=tx_hist[-1], times=rt,
-                    update_norm=np.asarray(outs["update_norm"]), lanes=lanes,
-                    rejected=rej,
-                    dropped=(
-                        np.asarray([n_dropped], np.int64)
-                        if n_dropped is not None
-                        else None
-                    ),
-                )
+                rejected_hist.append(rej)
+                times.append(rt)
+                accs.append(acc)
+                sel_hist.append(sel)
+                pms_hist.append(pms)
+                tx_hist.append(np.asarray(outs["tx_params"], np.float64))
+                wire_hist.append(wire.sum(axis=1))
+            with phase_timer(prof, "record"):
+                if recorder is not None:
+                    # one vectorized append per chunk, straight off the stacked
+                    # out leaves the device_get above already fetched
+                    recorder.on_sync_chunk(
+                        t0=t0, acc=acc, sel=sel, pms=pms, wire=wire,
+                        tx=tx_hist[-1], times=rt,
+                        update_norm=np.asarray(outs["update_norm"]), lanes=lanes,
+                        rejected=rej,
+                        dropped=(
+                            np.asarray([n_dropped], np.int64)
+                            if n_dropped is not None
+                            else None
+                        ),
+                    )
             if progress:
                 for i in _progress_rows(t0, n, chunk, cfg.rounds):
                     emit(format_sync_progress(
@@ -798,6 +800,7 @@ def build_async_step(env: phases.RoundEnv, pipeline: RoundPipeline, faults=None)
     max_norm = float(faults.max_update_norm) if faulty else 0.0
     corrupt_scale = float(faults.corrupt_scale) if faulty else 0.0
 
+    @phases.scoped("fl.event")
     def _async_body(
         state: AsyncState,
         t: jnp.ndarray,
@@ -830,24 +833,26 @@ def build_async_step(env: phases.RoundEnv, pipeline: RoundPipeline, faults=None)
         land_cid = jnp.where(land, cids, c)
         participation = prev_part.at[land_cid].add(1, mode="drop")
 
-        menv = env.take(cids)
-        cctx = phases.RoundContext(
-            t=t,
-            global_params=g,
-            local_params=tree_take(state.local_params, cids) if stateful else None,
-            select=land,
-            pms=state.slot_pms,
-            share=share_m,
-            residual=tree_take(state.residual, cids),
-            participation=jnp.take(participation, cids),
-            cohort_idx=cids,
-            cohort_mask=land,
-            dispatch_params=state.slot_params,
-            staleness=staleness,
-            rng_fit=r_fit,
-            rng_codec=r_codec,
-            rng_sel=r_sel,
-        )
+        # --- gather: each slot's client data and per-client state ---
+        with jax.named_scope("fl.gather"):
+            menv = env.take(cids)
+            cctx = phases.RoundContext(
+                t=t,
+                global_params=g,
+                local_params=tree_take(state.local_params, cids) if stateful else None,
+                select=land,
+                pms=state.slot_pms,
+                share=share_m,
+                residual=tree_take(state.residual, cids),
+                participation=jnp.take(participation, cids),
+                cohort_idx=cids,
+                cohort_mask=land,
+                dispatch_params=state.slot_params,
+                staleness=staleness,
+                rng_fit=r_fit,
+                rng_codec=r_codec,
+                rng_sel=r_sel,
+            )
 
         # --- each slot lane trains from its own dispatch snapshot ---
         cctx = cctx._replace(train_model=pipeline.personalizer.train_model(cctx, menv))
@@ -862,56 +867,59 @@ def build_async_step(env: phases.RoundEnv, pipeline: RoundPipeline, faults=None)
             cctx = cctx._replace(
                 trained=apply_corruption(cctx.trained, kinds_m, corrupt_scale)
             )
-        if stateful:
-            cctx = cctx._replace(
-                new_local=jax.tree.map(
-                    lambda new, old: jnp.where(_lane(land, new), new, old),
-                    cctx.trained,
-                    pipeline.personalizer.local_fallback(cctx, menv),
+        with jax.named_scope("fl.personalize"):
+            if stateful:
+                cctx = cctx._replace(
+                    new_local=jax.tree.map(
+                        lambda new, old: jnp.where(_lane(land, new), new, old),
+                        cctx.trained,
+                        pipeline.personalizer.local_fallback(cctx, menv),
+                    )
                 )
-            )
         # --- wire codec: landing slots' deltas vs their snapshots ---
         local_before = cctx.local_params if stateful else None
         res_before = cctx.residual
         cctx = pipeline.transmit.transmit(cctx, menv)
         # --- finite-delta guard (always on): non-finite / norm-exploded
         # landings are masked out of the merge and their state reverted ---
-        ok, n_rejected = finite_update_guard(land, cctx.update_norm, max_norm)
-        cctx = cctx._replace(
-            select=land & ok,
-            update_norm=jnp.where(ok, cctx.update_norm, jnp.take(state.update_norm, cids)),
-        )
-        if res_before is not None:
+        with jax.named_scope("fl.transmit"):
+            ok, n_rejected = finite_update_guard(land, cctx.update_norm, max_norm)
             cctx = cctx._replace(
-                residual=jax.tree.map(
-                    lambda new, old: jnp.where(_lane(ok, new), new, old),
-                    cctx.residual,
-                    res_before,
-                )
+                select=land & ok,
+                update_norm=jnp.where(ok, cctx.update_norm, jnp.take(state.update_norm, cids)),
             )
-        if stateful:
-            cctx = cctx._replace(
-                new_local=jax.tree.map(
-                    lambda new, old: jnp.where(_lane(ok, new), new, old),
-                    cctx.new_local,
-                    local_before,
+            if res_before is not None:
+                cctx = cctx._replace(
+                    residual=jax.tree.map(
+                        lambda new, old: jnp.where(_lane(ok, new), new, old),
+                        cctx.residual,
+                        res_before,
+                    )
                 )
-            )
+            if stateful:
+                cctx = cctx._replace(
+                    new_local=jax.tree.map(
+                        lambda new, old: jnp.where(_lane(ok, new), new, old),
+                        cctx.new_local,
+                        local_before,
+                    )
+                )
         # --- staleness-weighted buffered merge into the current model ---
         cctx = pipeline.aggregator.aggregate(cctx, menv)
 
         # --- scatter landing lanes into the (C, ...) client state ---
-        new_local = (
-            tree_scatter(state.local_params, land_cid, cctx.new_local, mode="drop")
-            if stateful
-            else None
-        )
-        new_residual = tree_scatter(state.residual, land_cid, cctx.residual, mode="drop")
-        update_norm = state.update_norm.at[land_cid].set(cctx.update_norm, mode="drop")
-        land_c = jnp.zeros((c,), bool).at[land_cid].set(True, mode="drop")
-        wire_paid_c = (
-            jnp.zeros((c,), jnp.float32).at[land_cid].set(cctx.wire_paid, mode="drop")
-        )
+        with jax.named_scope("fl.scatter"):
+            new_local = (
+                tree_scatter(state.local_params, land_cid, cctx.new_local, mode="drop")
+                if stateful
+                else None
+            )
+            new_residual = tree_scatter(state.residual, land_cid, cctx.residual, mode="drop")
+            update_norm = state.update_norm.at[land_cid].set(cctx.update_norm, mode="drop")
+            land_c = jnp.zeros((c,), bool).at[land_cid].set(True, mode="drop")
+            wire_paid_c = (
+                jnp.zeros((c,), jnp.float32).at[land_cid].set(cctx.wire_paid, mode="drop")
+            )
         share_c = layer_share_mask(n_layers, state.client_pms)  # (C, L)
         wire_prospective, _ = pipeline.transmit.wire_costs(g, share_c, land_c)
 
@@ -946,28 +954,30 @@ def build_async_step(env: phases.RoundEnv, pipeline: RoundPipeline, faults=None)
         pctx = pctx._replace(next_pms=pipeline.layer_policy.next_pms(pctx, env, n_layers))
 
         # --- slot assignment: wanted idle clients -> freed slots, ascending
-        # ids on both sides; never let the queue drain ---
-        want = pctx.next_select & idle_now         # (C,)
-        free = land | ~active                      # (M,)
-        n_assign = jnp.minimum(jnp.sum(want), jnp.sum(free))
-        slot_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
-        cand_order = jnp.argsort(~want, stable=True)  # wanted ids first, ascending
-        assigned = free & (slot_rank < n_assign)
-        new_cid = jnp.take(cand_order, jnp.clip(slot_rank, 0, c - 1))
-        need_force = force & (n_assign == 0)
-        dispatched = jnp.where(need_force, land, assigned)
-        new_slot_client = jnp.where(assigned, new_cid, cids)
-        # pms is frozen at dispatch (like the snapshot): the share mask a
-        # client lands with is the one its completion time was charged for
-        disp_pms = jnp.take(pctx.next_pms, new_slot_client)
-        new_slot_pms = jnp.where(dispatched, disp_pms, state.slot_pms)
-        disp_cid = jnp.where(dispatched, new_slot_client, c)
-        new_client_pms = state.client_pms.at[disp_cid].set(disp_pms, mode="drop")
-        new_slot_params = jax.tree.map(
-            lambda s, gl: jnp.where(_lane(dispatched, s), jnp.broadcast_to(gl, s.shape), s),
-            state.slot_params,
-            pctx.new_global,
-        )
+        # ids on both sides; never let the queue drain. Writing the new
+        # model into the dispatched slots is this step's scatter ---
+        with jax.named_scope("fl.scatter"):
+            want = pctx.next_select & idle_now         # (C,)
+            free = land | ~active                      # (M,)
+            n_assign = jnp.minimum(jnp.sum(want), jnp.sum(free))
+            slot_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
+            cand_order = jnp.argsort(~want, stable=True)  # wanted ids first, ascending
+            assigned = free & (slot_rank < n_assign)
+            new_cid = jnp.take(cand_order, jnp.clip(slot_rank, 0, c - 1))
+            need_force = force & (n_assign == 0)
+            dispatched = jnp.where(need_force, land, assigned)
+            new_slot_client = jnp.where(assigned, new_cid, cids)
+            # pms is frozen at dispatch (like the snapshot): the share mask a
+            # client lands with is the one its completion time was charged for
+            disp_pms = jnp.take(pctx.next_pms, new_slot_client)
+            new_slot_pms = jnp.where(dispatched, disp_pms, state.slot_pms)
+            disp_cid = jnp.where(dispatched, new_slot_client, c)
+            new_client_pms = state.client_pms.at[disp_cid].set(disp_pms, mode="drop")
+            new_slot_params = jax.tree.map(
+                lambda s, gl: jnp.where(_lane(dispatched, s), jnp.broadcast_to(gl, s.shape), s),
+                state.slot_params,
+                pctx.new_global,
+            )
 
         land_f = land.astype(jnp.float32)
         new_state = AsyncState(
@@ -1227,71 +1237,73 @@ class AsyncScheduler:
                 # history accumulated so far instead of deadlocking
                 break
             k = max(1, min(buffer_k, n_active))
-            # earliest finishers land; ties break by client id (deterministic)
-            landers = queue.pop_k(k)
-            if faulty:
-                codes = slot_fail[landers]
-                ok_l = landers[codes == 0]
-                bad = landers[codes != 0]
-                pend_timeout += int((codes == 2).sum())
-                # capture notice times BEFORE retry pushes overwrite them
-                notice_max = float(queue.finish[landers].max())
-                can_retry = retries[bad] < faults.max_retries
-                retry_slots = bad[can_retry]
-                drop_slots = bad[~can_retry]
-                for s in retry_slots:
-                    # exponential-backoff re-dispatch of the SAME client on
-                    # the same slot and snapshot: the failure is noticed at
-                    # the popped finish time, the retry starts after the
-                    # backoff, with fresh fault draws at the current model
-                    # version (transient slowness / crashes clear on retry)
-                    retries[s] += 1
-                    cid = int(slot_client[s])
-                    backoff = faults.backoff_s * (2.0 ** float(retries[s] - 1))
-                    d_r, code_r, kind_r = _arm_faults(
-                        [cid], clock_fn.durations(client_pms[[cid]], cids=[cid]),
-                        version,
-                    )
-                    slot_fail[s] = code_r[0]
-                    slot_kind[s] = kind_r[0]
-                    queue.push(s, float(queue.finish[s]) + backoff + float(d_r[0]), cid)
-                pend_retried += int(retry_slots.size)
-                if drop_slots.size:
-                    # retries exhausted: free the slot and the client — the
-                    # step's idle-assignment path backfills from selection
-                    pend_dropped += int(drop_slots.size)
-                    active[drop_slots] = False
-                    in_flight_clients[slot_client[drop_slots]] = False
-                if ok_l.size == 0 and drop_slots.size == 0:
-                    continue  # pure-retry event: no aggregation happens
-                landers = ok_l
-                land = np.zeros((m,), bool)
-                land[landers] = True
-                land_finish = queue.finish[landers].copy()
-                new_clock = notice_max + comm.server_latency_s
-                force = bool(int((active & ~land).sum()) == 0)
-            else:
-                land = np.zeros((m,), bool)
-                land[landers] = True
-                land_finish = queue.finish[landers].copy()
-                new_clock = float(land_finish.max()) + comm.server_latency_s
-                force = bool(n_active - k == 0)
-            staleness = np.where(land, version - dispatch_version, 0).astype(np.int32)
-            landed_clients = slot_client[landers]
-            idle_now = ~in_flight_clients
-            idle_now[landed_clients] = True
+            with phase_timer(prof, "queue"):
+                # earliest finishers land; ties break by client id (deterministic)
+                landers = queue.pop_k(k)
+                if faulty:
+                    codes = slot_fail[landers]
+                    ok_l = landers[codes == 0]
+                    bad = landers[codes != 0]
+                    pend_timeout += int((codes == 2).sum())
+                    # capture notice times BEFORE retry pushes overwrite them
+                    notice_max = float(queue.finish[landers].max())
+                    can_retry = retries[bad] < faults.max_retries
+                    retry_slots = bad[can_retry]
+                    drop_slots = bad[~can_retry]
+                    for s in retry_slots:
+                        # exponential-backoff re-dispatch of the SAME client on
+                        # the same slot and snapshot: the failure is noticed at
+                        # the popped finish time, the retry starts after the
+                        # backoff, with fresh fault draws at the current model
+                        # version (transient slowness / crashes clear on retry)
+                        retries[s] += 1
+                        cid = int(slot_client[s])
+                        backoff = faults.backoff_s * (2.0 ** float(retries[s] - 1))
+                        d_r, code_r, kind_r = _arm_faults(
+                            [cid], clock_fn.durations(client_pms[[cid]], cids=[cid]),
+                            version,
+                        )
+                        slot_fail[s] = code_r[0]
+                        slot_kind[s] = kind_r[0]
+                        queue.push(s, float(queue.finish[s]) + backoff + float(d_r[0]), cid)
+                    pend_retried += int(retry_slots.size)
+                    if drop_slots.size:
+                        # retries exhausted: free the slot and the client — the
+                        # step's idle-assignment path backfills from selection
+                        pend_dropped += int(drop_slots.size)
+                        active[drop_slots] = False
+                        in_flight_clients[slot_client[drop_slots]] = False
+                    if ok_l.size == 0 and drop_slots.size == 0:
+                        continue  # pure-retry event: no aggregation happens
+                    landers = ok_l
+                    land = np.zeros((m,), bool)
+                    land[landers] = True
+                    land_finish = queue.finish[landers].copy()
+                    new_clock = notice_max + comm.server_latency_s
+                    force = bool(int((active & ~land).sum()) == 0)
+                else:
+                    land = np.zeros((m,), bool)
+                    land[landers] = True
+                    land_finish = queue.finish[landers].copy()
+                    new_clock = float(land_finish.max()) + comm.server_latency_s
+                    force = bool(n_active - k == 0)
+                staleness = np.where(land, version - dispatch_version, 0).astype(np.int32)
+                landed_clients = slot_client[landers]
+                idle_now = ~in_flight_clients
+                idle_now[landed_clients] = True
 
-            args = (
-                state,
-                jnp.asarray(t),
-                jnp.asarray(land),
-                jnp.asarray(staleness),
-                jnp.asarray(active),
-                jnp.asarray(idle_now),
-                jnp.asarray(force),
-            )
-            if faulty:
-                args = args + (jnp.asarray(slot_kind),)
+            with phase_timer(prof, "stage"):
+                args = (
+                    state,
+                    jnp.asarray(t),
+                    jnp.asarray(land),
+                    jnp.asarray(staleness),
+                    jnp.asarray(active),
+                    jnp.asarray(idle_now),
+                    jnp.asarray(force),
+                )
+                if faulty:
+                    args = args + (jnp.asarray(slot_kind),)
             if prof is not None:
                 prof.begin_chunk(t, 1)
                 if not isinstance(step, jax.stages.Compiled):
@@ -1306,73 +1318,76 @@ class AsyncScheduler:
             if prof is not None:
                 prof.end_chunk()
 
-            dispatched = np.asarray(out["dispatched"])
-            slot_client = np.asarray(out["slot_client"], np.int32)
-            client_pms = np.asarray(out["client_pms"], np.int32)
-            active = (active & ~land) | dispatched
-            in_flight_clients[landed_clients] = False
-            in_flight_clients[slot_client[dispatched]] = True
-            # re-arm only the dispatched slots: subset-duration rows are
-            # bitwise the full-lane rows (elementwise model), so the event
-            # clock never materializes a (C,) vector per event
-            disp_slots = np.nonzero(dispatched)[0]
-            if disp_slots.size:
-                disp_cids = slot_client[disp_slots]
-                d_disp = clock_fn.durations(client_pms[disp_cids], cids=disp_cids)
-                if faulty:
-                    # fresh fault draws at the version these slots train from
-                    d_disp, code_d, kind_d = _arm_faults(
-                        disp_cids, d_disp, version + 1
-                    )
-                    slot_fail[disp_slots] = code_d
-                    slot_kind[disp_slots] = kind_d
-                    retries[disp_slots] = 0
-                for s, f, cid in zip(disp_slots, new_clock + d_disp, disp_cids):
-                    queue.push(int(s), float(f), int(cid))
-            dispatch_version = np.where(dispatched, version + 1, dispatch_version)
+            with phase_timer(prof, "queue"):
+                dispatched = np.asarray(out["dispatched"])
+                slot_client = np.asarray(out["slot_client"], np.int32)
+                client_pms = np.asarray(out["client_pms"], np.int32)
+                active = (active & ~land) | dispatched
+                in_flight_clients[landed_clients] = False
+                in_flight_clients[slot_client[dispatched]] = True
+                # re-arm only the dispatched slots: subset-duration rows are
+                # bitwise the full-lane rows (elementwise model), so the event
+                # clock never materializes a (C,) vector per event
+                disp_slots = np.nonzero(dispatched)[0]
+                if disp_slots.size:
+                    disp_cids = slot_client[disp_slots]
+                    d_disp = clock_fn.durations(client_pms[disp_cids], cids=disp_cids)
+                    if faulty:
+                        # fresh fault draws at the version these slots train from
+                        d_disp, code_d, kind_d = _arm_faults(
+                            disp_cids, d_disp, version + 1
+                        )
+                        slot_fail[disp_slots] = code_d
+                        slot_kind[disp_slots] = kind_d
+                        retries[disp_slots] = 0
+                    for s, f, cid in zip(disp_slots, new_clock + d_disp, disp_cids):
+                        queue.push(int(s), float(f), int(cid))
+                dispatch_version = np.where(dispatched, version + 1, dispatch_version)
 
-            accs.append(out["acc"])
-            sel_hist.append(np.asarray(out["selected"]))
-            tx_hist.append(float(out["tx_params"]))
-            pms_hist.append(out["pms"])
-            if n_edges >= 1:
-                # hop-2 bytes for this event's landers; the event clock
-                # itself stays flat (the edge forward leg is modeled in the
-                # sync barrier's round time only)
-                edge_hist.append(
-                    edge_hop_bytes(
-                        sel_hist[-1][None], np.asarray(out["pms"])[None],
-                        layer_sizes, edge_ids, n_edges,
-                    )[0]
-                )
-            wire_hist.append(np.asarray(out["wire_per_client"], np.float64).sum())
-            times.append(new_clock - sim_clock)
-            clock_hist.append(new_clock)
-            stale_hist.append(float(out["staleness_mean"]))
-            flight_hist.append(int(in_flight_clients.sum()))
-            rejected_hist.append(int(out["rejected"]) if "rejected" in out else 0)
-            if recorder is not None:
-                fault_kw = {}
-                if faulty:
-                    fault_kw = dict(
-                        retried=pend_retried, timed_out=pend_timeout,
-                        dropped=pend_dropped,
+            with phase_timer(prof, "account"):
+                accs.append(out["acc"])
+                sel_hist.append(np.asarray(out["selected"]))
+                tx_hist.append(float(out["tx_params"]))
+                pms_hist.append(out["pms"])
+                if n_edges >= 1:
+                    # hop-2 bytes for this event's landers; the event clock
+                    # itself stays flat (the edge forward leg is modeled in the
+                    # sync barrier's round time only)
+                    edge_hist.append(
+                        edge_hop_bytes(
+                            sel_hist[-1][None], np.asarray(out["pms"])[None],
+                            layer_sizes, edge_ids, n_edges,
+                        )[0]
                     )
-                recorder.on_async_event(
-                    t=t, acc=np.asarray(out["acc"]), sel=sel_hist[-1],
-                    tx=tx_hist[-1], pms=pms_hist[-1], wire=wire_hist[-1],
-                    dt=times[-1], new_clock=new_clock,
-                    staleness_mean=stale_hist[-1], in_flight=flight_hist[-1],
-                    buffer_k=k, update_norm=np.asarray(out["update_norm"]),
-                    merge_discount=float(out["merge_discount_mean"]),
-                    landed_clients=landed_clients, landed_finish=land_finish,
-                    landed_staleness=staleness[landers],
-                    rejected=rejected_hist[-1], **fault_kw,
-                )
-                if dispatched.any():  # re-dispatches cut at the new clock
-                    recorder.on_async_dispatch(
-                        slot_client[dispatched], new_clock, client_pms
+                wire_hist.append(np.asarray(out["wire_per_client"], np.float64).sum())
+                times.append(new_clock - sim_clock)
+                clock_hist.append(new_clock)
+                stale_hist.append(float(out["staleness_mean"]))
+                flight_hist.append(int(in_flight_clients.sum()))
+                rejected_hist.append(int(out["rejected"]) if "rejected" in out else 0)
+            with phase_timer(prof, "record"):
+                if recorder is not None:
+                    fault_kw = {}
+                    if faulty:
+                        fault_kw = dict(
+                            retried=pend_retried, timed_out=pend_timeout,
+                            dropped=pend_dropped,
+                        )
+                    recorder.on_async_event(
+                        t=t, acc=np.asarray(out["acc"]), sel=sel_hist[-1],
+                        tx=tx_hist[-1], pms=pms_hist[-1], wire=wire_hist[-1],
+                        dt=times[-1], new_clock=new_clock,
+                        staleness_mean=stale_hist[-1], in_flight=flight_hist[-1],
+                        buffer_k=k, update_norm=np.asarray(out["update_norm"]),
+                        merge_discount=float(out["merge_discount_mean"]),
+                        landed_clients=landed_clients, landed_finish=land_finish,
+                        landed_staleness=staleness[landers],
+                        rejected=rejected_hist[-1], **fault_kw,
                     )
+                    if dispatched.any():  # re-dispatches cut at the new clock
+                        recorder.on_async_dispatch(
+                            slot_client[dispatched], new_clock, client_pms
+                        )
             pend_retried = pend_timeout = pend_dropped = 0
             sim_clock = new_clock
             version += 1

@@ -154,22 +154,24 @@ def build_sharded_round_step(
         )
         cctx = cctx._replace(train_model=pipeline.personalizer.train_model(cctx, cenv))
         cctx = pipeline.trainer.fit(cctx, cenv)
-        if stateful:
-            cctx = cctx._replace(
-                new_local=jax.tree.map(
-                    lambda new, old: jnp.where(
-                        cmask.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
-                    ),
-                    cctx.trained,
-                    pipeline.personalizer.local_fallback(cctx, cenv),
+        with jax.named_scope("fl.personalize"):
+            if stateful:
+                cctx = cctx._replace(
+                    new_local=jax.tree.map(
+                        lambda new, old: jnp.where(
+                            cmask.reshape((-1,) + (1,) * (new.ndim - 1)), new, old
+                        ),
+                        cctx.trained,
+                        pipeline.personalizer.local_fallback(cctx, cenv),
+                    )
                 )
-            )
         cctx = pipeline.transmit.transmit(cctx, cenv)
         # shard-local weighted partial sums + one psum over 'cohort' — the
         # new global model is identical (replicated) on every device
         cctx = aggregator.aggregate(cctx, cenv)
         return cctx.new_global, cctx.new_local, cctx.residual, cctx.update_norm
 
+    @phases.scoped("fl.round")
     def round_step(state: RoundState, t: jnp.ndarray):
         g = state.global_params
         n_layers = len(g)
@@ -182,25 +184,26 @@ def build_sharded_round_step(
             r_codec = None
 
         # --- gather: selection mask -> fixed-size cohort (K,) ---
-        idx = cohort_indices(state.select, cohort_k)
-        cmask = jnp.take(state.select, idx)
-        executed = jnp.zeros(state.select.shape, bool).at[idx].set(cmask)
-        prev_part = (
-            state.participation
-            if state.participation is not None
-            else jnp.zeros(state.select.shape, jnp.int32)
-        )
-        participation = prev_part + executed.astype(jnp.int32)
-        cenv = env.take(idx)
-        loc_c = tree_take(state.local_params, idx) if stateful else None
-        res_c = tree_take(state.residual, idx)
-        slabs = (cenv.x_tr, cenv.y_tr, cenv.m_tr, cenv.x_te, cenv.y_te,
-                 cenv.m_te, cenv.n_samples, cenv.delay)
+        with jax.named_scope("fl.gather"):
+            idx = cohort_indices(state.select, cohort_k)
+            cmask = jnp.take(state.select, idx)
+            executed = jnp.zeros(state.select.shape, bool).at[idx].set(cmask)
+            prev_part = (
+                state.participation
+                if state.participation is not None
+                else jnp.zeros(state.select.shape, jnp.int32)
+            )
+            participation = prev_part + executed.astype(jnp.int32)
+            cenv = env.take(idx)
+            loc_c = tree_take(state.local_params, idx) if stateful else None
+            res_c = tree_take(state.residual, idx)
+            slabs = (cenv.x_tr, cenv.y_tr, cenv.m_tr, cenv.x_te, cenv.y_te,
+                     cenv.m_te, cenv.n_samples, cenv.delay)
+            args = (g, t, r_fit, r_codec, idx, cmask, jnp.take(state.pms, idx),
+                    jnp.take(share, idx, axis=0), jnp.take(participation, idx),
+                    loc_c, res_c, slabs)
 
         # --- compute phases on K/D lanes per device ---
-        args = (g, t, r_fit, r_codec, idx, cmask, jnp.take(state.pms, idx),
-                jnp.take(share, idx, axis=0), jnp.take(participation, idx),
-                loc_c, res_c, slabs)
         in_specs = (rep, rep, rep, rep, lane, lane, lane, lane, lane,
                     tree_lane_pspecs(loc_c, mesh),
                     tree_lane_pspecs(res_c, mesh),
@@ -216,17 +219,19 @@ def build_sharded_round_step(
             out_specs=out_specs, check_vma=False,
         )(*args)
 
-        # --- scatter: cohort results back into the (C, ...) server state ---
-        new_local = (
-            tree_scatter(state.local_params, idx, new_local_c) if stateful else None
-        )
-        new_residual = tree_scatter(state.residual, idx, new_res_c)
-        prev_norm = (
-            state.update_norm
-            if state.update_norm is not None
-            else jnp.zeros(state.select.shape, jnp.float32)
-        )
-        update_norm = prev_norm.at[idx].set(unorm_c)
+        # --- scatter: cohort results back into the (C, ...) server state
+        # (the all-gather of the lane-sharded results lands here) ---
+        with jax.named_scope("fl.scatter"):
+            new_local = (
+                tree_scatter(state.local_params, idx, new_local_c) if stateful else None
+            )
+            new_residual = tree_scatter(state.residual, idx, new_res_c)
+            prev_norm = (
+                state.update_norm
+                if state.update_norm is not None
+                else jnp.zeros(state.select.shape, jnp.float32)
+            )
+            update_norm = prev_norm.at[idx].set(unorm_c)
         wire_prospective, wire_paid = pipeline.transmit.wire_costs(
             g, share, executed
         )

@@ -10,9 +10,20 @@ run's device trajectory is bit-identical to an unrecorded one):
   *simulated* clock (per-client dispatch/train/upload lanes, aggregation
   instants, sync round/chunk spans) + the schema validator CI runs.
 - ``repro.obs.profile`` — opt-in wall-clock profiling of the real loop
-  (compile vs dispatch vs device_get per chunk, jit cache misses,
-  ``jax.live_arrays()`` memory watermark, optional ``jax.profiler``
-  capture).
+  (compile vs dispatch vs device_get per chunk, the scheduler's host
+  phases ``queue``/``stage``/``account``/``record``, jit cache misses, the
+  devices' peak memory, optional ``jax.profiler`` capture).
+
+On the device side, every op of the round step carries a named scope in
+its HLO ``op_name`` (``repro.fl.phases``): ``fl.round`` / ``fl.event`` /
+``fl.chunk`` for the body, and ``fl.gather``, ``fl.personalize``,
+``fl.train``, ``fl.transmit``, ``fl.aggregate``, ``fl.scatter``,
+``fl.eval``, ``fl.select`` for the phase inside it. With
+``RunRecorder(profile=True, jax_trace_dir=...)`` the captured trace holds
+both: each host phase as an ``fl.<phase>`` annotation on the trace's
+clock, beside the device ops, whose scopes TensorBoard's profile plugin
+reads from the HLO the trace keeps (``bench.scopes`` splits a TPU trace's
+busy time by them).
 
 Attach a recorder through the stable entry point::
 

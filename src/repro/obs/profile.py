@@ -14,13 +14,19 @@ drive the device: per chunk (sync) or per event (async) it splits
                    on CPU it includes device compute),
 - ``device_get`` — the blocking fetch of the chunk's stacked out leaves,
 
-plus a jit cache-miss count (one per ``compile``) and a device-memory
-watermark sampled from ``jax.live_arrays()`` after each chunk — the
-always-on generalization of the loop bench's one-shot donation audit.
+plus the host phases of the scheduler loop between fetching one chunk
+or event and dispatching the next (``queue``, ``stage``, ``account``,
+``record``; see ``repro.fl.sched``), a jit cache-miss count (one per
+``compile``), and the devices' peak memory at the end of the run
+(``memory_stats()["peak_bytes_in_use"]``; null where the backend keeps no
+such count, as the CPU).
 
 ``jax_trace_dir`` additionally captures a ``jax.profiler`` trace
 (TensorBoard/Perfetto-loadable) around the run — behind its own flag
 because the capture has real overhead and writes its own artifact tree.
+While it runs, every phase is also a ``TraceAnnotation`` named
+``fl.<phase>`` on the trace's host clock, beside the device ops that the
+round step's ``fl.*`` named scopes label.
 
 The profiler is opt-in end to end: the schedulers hold ``None`` unless
 ``RunRecorder(profile=True)`` attached one, and every hook sits behind an
@@ -45,15 +51,25 @@ def phase_timer(prof: "Profiler | None", name: str):
     return prof.phase(name)
 
 
+def _device_peak_bytes() -> int | None:
+    """Largest ``peak_bytes_in_use`` over the local devices, or None where
+    the backend reports no memory statistics."""
+    peaks = [
+        (d.memory_stats() or {}).get("peak_bytes_in_use")
+        for d in jax.local_devices()
+    ]
+    peaks = [p for p in peaks if p is not None]
+    return int(max(peaks)) if peaks else None
+
+
 class Profiler:
-    """Accumulates per-chunk phase timings + memory watermark; pure host
-    state, summarized by ``summary()`` into ``profile.json``."""
+    """Accumulates per-chunk phase timings; pure host state, summarized by
+    ``summary()`` into ``profile.json``."""
 
     def __init__(self, jax_trace_dir: str | None = None):
         self.totals: dict[str, float] = {}
         self.chunks: list[dict] = []
         self.cache_misses = 0
-        self.peak_live_bytes = 0
         self._current: dict | None = None
         self._jax_trace_dir = jax_trace_dir
         self._jax_tracing = False
@@ -79,14 +95,17 @@ class Profiler:
         self.chunks.append(self._current)
 
     def end_chunk(self):
-        self.sample_memory()
         self._current = None
 
     @contextlib.contextmanager
     def phase(self, name: str):
+        annotation = contextlib.nullcontext()
+        if self._jax_tracing:
+            annotation = jax.profiler.TraceAnnotation(f"fl.{name}")
         t0 = time.perf_counter()
         try:
-            yield
+            with annotation:
+                yield
         finally:
             dt = time.perf_counter() - t0
             self.totals[name] = self.totals.get(name, 0.0) + dt
@@ -95,22 +114,12 @@ class Profiler:
             if self._current is not None:
                 self._current[f"{name}_s"] = self._current.get(f"{name}_s", 0.0) + dt
 
-    def sample_memory(self):
-        live = sum(
-            a.size * a.dtype.itemsize
-            for a in jax.live_arrays()
-            if not a.is_deleted()
-        )
-        self.peak_live_bytes = max(self.peak_live_bytes, int(live))
-        if self._current is not None:
-            self._current["live_bytes"] = int(live)
-
     # -- output ------------------------------------------------------------
     def summary(self) -> dict:
         return {
             "totals_s": dict(self.totals),
             "jit_cache_misses": self.cache_misses,
-            "peak_live_bytes": self.peak_live_bytes,
+            "device_peak_bytes": _device_peak_bytes(),
             "jax_trace_dir": self._jax_trace_dir,
             "chunks": self.chunks,
         }

@@ -14,6 +14,7 @@ workers all import this file.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,42 +76,59 @@ def test_quantize_pair_compiles_for_v5e(one_chip, bits, n):
     assert text.count(KERNEL) >= 2, "quantize and dequantize kernels"
 
 
-@pytest.fixture
-def tpu_kernels(monkeypatch):
-    """Make the quantize wrappers pick the Pallas kernel as they do on a
-    TPU; traces made meanwhile are dropped on both sides."""
-    from repro.kernels.quantize import ops
-
-    jax.clear_caches()
-    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
-    yield
-    monkeypatch.undo()
-    jax.clear_caches()
-
-
-def test_int8_round_step_compiles_for_v5e(one_chip, tpu_kernels):
-    """One round of the paper recipe with the int8 codec, on the uci-har
-    stand-in at har-mlp's published widths, compiles for one chip with the
-    codec's Pallas kernels inside."""
+@pytest.fixture(scope="module")
+def int8_round_text(one_chip):
+    """The optimized program of one round of the paper recipe with the int8
+    codec, on the uci-har stand-in at har-mlp's published widths, compiled
+    for one chip with the quantize wrappers picking the Pallas kernel as
+    they do on a TPU (traces made meanwhile are dropped on both sides)."""
     from repro.configs.har_mlp import fl_defaults
     from repro.data import make_har_dataset
     from repro.fl import api
     from repro.fl.sched import _setup_run
+    from repro.kernels.quantize import ops
     from repro.models.mlp import mlp_accuracy, mlp_loss
 
     cfg = fl_defaults()
     cfg = dataclasses.replace(cfg, codec=dataclasses.replace(cfg.codec, spec="int8"))
     ds = make_har_dataset("uci-har", seed=0)
-    su = _setup_run(ds, cfg, None, mlp_loss, mlp_accuracy, None, None, None)
-    state = su.initial_state()
     # the env's data slabs become arguments, so the program holds no array
     # placed on this host's CPU
     slabs = ("x_tr", "y_tr", "m_tr", "x_te", "y_te", "m_te", "n_samples", "delay")
+    jax.clear_caches()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_default_interpret", lambda: False)
+        su = _setup_run(ds, cfg, None, mlp_loss, mlp_accuracy, None, None, None)
+        state = su.initial_state()
 
-    def round_step(state, t, data):
-        env = dataclasses.replace(su.env, **dict(zip(slabs, data)))
-        return api.build_round_step(env, su.pipeline, cfg.execution)(state, t)
+        def round_step(state, t, data):
+            env = dataclasses.replace(su.env, **dict(zip(slabs, data)))
+            return api.build_round_step(env, su.pipeline, cfg.execution)(state, t)
 
-    args = (state, jnp.int32(0), tuple(getattr(su.env, s) for s in slabs))
-    compiled = jax.jit(round_step).lower(*_abstract(args, one_chip)).compile()
-    assert compiled.as_text().count(KERNEL) >= 2
+        args = (state, jnp.int32(0), tuple(getattr(su.env, s) for s in slabs))
+        text = jax.jit(round_step).lower(*_abstract(args, one_chip)).compile().as_text()
+    jax.clear_caches()
+    return text
+
+
+def test_int8_round_step_compiles_for_v5e(int8_round_text):
+    """One int8 round compiles for one chip with the codec's Pallas kernels
+    inside."""
+    assert int8_round_text.count(KERNEL) >= 2
+
+
+def test_codec_kernels_keep_the_name_the_benchmark_finds(int8_round_text):
+    """The codec's kernels are custom calls named after their jitted
+    wrappers, inside the round's ``fl.transmit`` scope: the benchmark's
+    ``quantize_roofline`` finds them by that name (its ``CODEC`` pattern),
+    one quantize and one dequantize per parameter leaf of a round."""
+    from bench.metrics.quantize_roofline import CODEC
+
+    kernels = [line for line in int8_round_text.splitlines()
+               if f'custom_call_target="{KERNEL}"' in line]
+    leaves = 2 * 4  # har-mlp: (weight, bias) x 4 layers
+    assert len(kernels) == 2 * leaves
+    for line in kernels:
+        name = line.strip().removeprefix("ROOT ").split(" = ", 1)[0]
+        assert CODEC.search(name), name
+        assert "/fl.transmit/" in re.search(r'op_name="([^"]*)"', line).group(1)
