@@ -47,6 +47,12 @@ optionally scaled by a per-client heterogeneity lane):
   scores. Wire traffic rides the same codec path (per-client EF residuals
   included), so async + compression + cost-aware selection compose.
 
+  Each event crosses the host/device boundary once each way: one packed
+  argument in (the event's host inputs in one int32 vector, handed to the
+  compiled step as numpy) and one packed result out (every ``out`` leaf
+  bitcast into one uint32 vector, fetched with one ``device_get``) — see
+  ``AsyncPacking`` and ``build_packed_async_step``.
+
 Both schedulers execute rounds through the cohort runtime (repro.fl.cohort
 gather/scatter): the sync step gathers the ``cohort_size`` selected
 clients' lanes per round, the async step's cohort lanes *are* the M
@@ -60,6 +66,7 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import math
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -99,12 +106,14 @@ from repro.obs.profile import phase_timer
 from repro.obs.record import format_async_progress, format_sync_progress
 
 __all__ = [
+    "AsyncPacking",
     "AsyncScheduler",
     "AsyncState",
     "ClientClock",
     "EventQueue",
     "SyncScheduler",
     "build_async_step",
+    "build_packed_async_step",
     "make_scheduler",
 ]
 
@@ -1030,6 +1039,106 @@ def build_async_step(env: phases.RoundEnv, pipeline: RoundPipeline, faults=None)
     return fault_async_step
 
 
+class AsyncPacking:
+    """Layout of the async step's one packed argument and one packed result.
+
+    In: one int32 vector ``[t, force, land (M), staleness (M), active (M),
+    idle_now (C)]``, then ``corrupt (M)`` when faults are on — offsets fixed
+    by M, C and the fault switch. Out: every ``out`` leaf as 32-bit words in
+    one uint32 vector, bools as 0/1 and int32/float32 bitcast, so the host
+    gets back the same bits the step computed. The out leaves' shapes and
+    dtypes are recorded when the step is traced.
+    """
+
+    def __init__(self, m: int, c: int, faulty: bool):
+        sizes = {"t": 1, "force": 1, "land": m, "staleness": m, "active": m, "idle_now": c}
+        if faulty:
+            sizes["corrupt"] = m
+        offsets = np.cumsum([0, *sizes.values()])
+        self.lanes = {k: (int(o), n) for (k, n), o in zip(sizes.items(), offsets)}
+        self.size = int(offsets[-1])
+        self.out_spec = None
+
+    def pack_in(self, t, force, land, staleness, active, idle_now, corrupt=None) -> np.ndarray:
+        """The event's host inputs as one int32 vector (host, numpy)."""
+        buf = np.empty((self.size,), np.int32)
+        values = dict(t=t, force=force, land=land, staleness=staleness,
+                      active=active, idle_now=idle_now, corrupt=corrupt)
+        for k, (o, n) in self.lanes.items():
+            buf[o:o + n] = values[k]
+        return buf
+
+    def unpack_in(self, buf: jnp.ndarray) -> tuple:
+        """``build_async_step``'s arguments after ``state``, sliced from the
+        packed vector (traced)."""
+        lane = {k: buf[o:o + n] for k, (o, n) in self.lanes.items()}
+        args = (
+            lane["t"][0],
+            lane["land"].astype(bool),
+            lane["staleness"],
+            lane["active"].astype(bool),
+            lane["idle_now"].astype(bool),
+            lane["force"][0].astype(bool),
+        )
+        return args + ((lane["corrupt"],) if "corrupt" in lane else ())
+
+    def pack_out(self, out) -> jnp.ndarray:
+        """The step's ``out`` tree as one uint32 vector (traced)."""
+        leaves, treedef = jax.tree.flatten(out)
+        self.out_spec = (treedef, [(x.shape, np.dtype(x.dtype)) for x in leaves])
+        words = []
+        for x in leaves:
+            if x.dtype == jnp.bool_:
+                x = x.astype(jnp.uint32)
+            elif x.dtype.itemsize == 4:
+                x = jax.lax.bitcast_convert_type(x, jnp.uint32)
+            else:
+                raise TypeError(f"cannot pack an out leaf of dtype {x.dtype}")
+            words.append(x.reshape(-1))
+        return jnp.concatenate(words)
+
+    def unpack_out(self, flat: np.ndarray) -> dict:
+        """The ``out`` dict from the fetched vector: numpy views with the
+        step's shapes and dtypes (bools as fresh arrays)."""
+        if self.out_spec is None:
+            raise RuntimeError("unpack_out before the packed step was traced")
+        treedef, spec = self.out_spec
+        leaves, o = [], 0
+        for shape, dtype in spec:
+            n = math.prod(shape)
+            w = flat[o:o + n]
+            o += n
+            leaves.append((w != 0 if dtype == np.bool_ else w.view(dtype)).reshape(shape))
+        return jax.tree.unflatten(treedef, leaves)
+
+
+def build_packed_async_step(
+    env: phases.RoundEnv, pipeline: RoundPipeline, m: int, faults=None
+):
+    """The jitted async step over one packed argument and one packed result.
+
+    Returns ``(step, packing)``: ``step(state, buf) -> (state, flat)`` runs
+    ``build_async_step``'s step on the inputs ``packing.pack_in`` wrote into
+    ``buf`` and returns its ``out`` as ``flat``, for ``packing.unpack_out``.
+    So an event crosses host to device once and back once. The optimization
+    barriers give the body the fusion boundaries of the unpacked step, whose
+    outputs it returns bit for bit.
+    """
+    faulty = faults is not None and faults.enabled
+    body = build_async_step(env, pipeline, faults=faults if faulty else None)
+    packing = AsyncPacking(m, env.n_clients, faulty)
+
+    def packed_step(state: AsyncState, buf: jnp.ndarray):
+        with jax.named_scope("fl.event"):
+            args = jax.lax.optimization_barrier(packing.unpack_in(buf))
+        state, out = body(state, *args)
+        with jax.named_scope("fl.event"):
+            state, out = jax.lax.optimization_barrier((state, out))
+            return state, packing.pack_out(out)
+
+    return jax.jit(packed_step), packing
+
+
 @dataclasses.dataclass
 class AsyncScheduler:
     """FedBuff-style event-driven server loop over M dispatch slots.
@@ -1044,6 +1153,14 @@ class AsyncScheduler:
     be in flight, the pre-slot behaviour). With ``max_concurrency=M_c`` at
     most ``M_c`` clients are ever in flight — FedBuff's concurrency cap,
     tunable independently of how many clients the selector scores.
+
+    An event hands the compiled step ``state`` plus one packed argument
+    (``AsyncPacking.pack_in``: ``t``, ``force`` and the landing, staleness,
+    active, idle and corruption lanes in one numpy int32 vector) and fetches
+    one packed result (``AsyncPacking.unpack_out``: the ``out`` leaves, bit
+    for bit), so the host pays one transfer each way instead of one per
+    argument and per leaf. The host-population loop
+    (``population.run_host_async``) keeps its own staging.
 
     The trajectory is a pure function of (data, cfg, pipeline, delays):
     device work is deterministic, and the queue breaks finish-time ties by
@@ -1133,9 +1250,7 @@ class AsyncScheduler:
             residual=su.residual0,
             participation=jnp.zeros((c,), jnp.int32),
         )
-        step = jax.jit(
-            build_async_step(su.env, su.pipeline, faults=faults if faulty else None)
-        )
+        step, packing = build_packed_async_step(su.env, su.pipeline, m, faults=faults)
         buffer_k = self.buffer_k or cfg.scheduler.buffer_k or max(1, c // 2)
         deadline = float(faults.deadline_s)
 
@@ -1295,15 +1410,11 @@ class AsyncScheduler:
             with phase_timer(prof, "stage"):
                 args = (
                     state,
-                    jnp.asarray(t),
-                    jnp.asarray(land),
-                    jnp.asarray(staleness),
-                    jnp.asarray(active),
-                    jnp.asarray(idle_now),
-                    jnp.asarray(force),
+                    packing.pack_in(
+                        t, force, land, staleness, active, idle_now,
+                        slot_kind if faulty else None,
+                    ),
                 )
-                if faulty:
-                    args = args + (jnp.asarray(slot_kind),)
             if prof is not None:
                 prof.begin_chunk(t, 1)
                 if not isinstance(step, jax.stages.Compiled):
@@ -1312,9 +1423,9 @@ class AsyncScheduler:
                     with prof.phase("compile"):
                         step = step.lower(*args).compile()
             with phase_timer(prof, "dispatch"):
-                state, out = step(*args)
+                state, flat = step(*args)
             with phase_timer(prof, "device_get"):
-                out = jax.device_get(out)
+                out = packing.unpack_out(jax.device_get(flat))
             if prof is not None:
                 prof.end_chunk()
 
