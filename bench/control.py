@@ -5,22 +5,24 @@ the control's, and those of planted faults, in one process.
         [--candidates program,control,half_batch,answer,unchanged]
 
 - ``program``: the timed path's first chunk, as a benchmark run takes it.
-- ``control``: the reference put in the program's place, with its matmuls
-  in the nearest precision below the configuration's (``high``: three
-  bfloat16 passes for float32 at ``highest``).
-- ``half_batch``, ``answer``, ``unchanged``: the reference in the
-  program's place with a fault planted: the second half of each batch left
-  out of the loss; client 0's accuracy answer altered; local training
-  returning its input.
 - ``no_exchange``: the program itself with the exchange between chips
   left out (every ``lax.psum`` returns its shard-local input), for a cell
   whose cohort is sharded.
+- any other name: a candidate of the configuration's model
+  (``bench/models/<model>.py``, ``CANDIDATES``), the model's reference put
+  in the program's place: ``control`` at the precision below the
+  configuration's, or with a fault planted. har-mlp has ``control``
+  (matmuls at ``high``: three bfloat16 passes for float32 at
+  ``highest``), ``half_batch`` (the second half of each batch left out of
+  the loss), ``answer`` (client 0's accuracy answer altered) and
+  ``unchanged`` (local training returning its input). The default is
+  ``program`` and every candidate of the model.
 
 Under the async scheduler the reference in the program's place follows the
 program's own event schedule (landings and dispatches).
 
-Each candidate's numbers (``bench.correct``) print as one JSON line. Not
-part of a benchmark run; ``--allow-cpu`` runs it off the chip (tests).
+Each candidate's numbers (the model's ``numbers``) print as one JSON line.
+Not part of a benchmark run; ``--allow-cpu`` runs it off the chip (tests).
 """
 
 from __future__ import annotations
@@ -34,13 +36,13 @@ import time
 from bench import run as bench_run
 
 
-def program_outs(workload, config, data, seed):
+def program_outs(model, workload, config, data, seed):
     from bench import harness
     from repro.fl.engine import run_federated
 
     window = harness.Window(0.0, time.perf_counter())
     try:
-        run_federated(data, bench_run.fl_config(workload, seed, 10 ** 9), recorder=window)
+        run_federated(data, model.fl_config(workload, config, seed, 10 ** 9), recorder=window)
     except harness.WindowClosed:
         pass
     return window.early_outs, window.decisions
@@ -60,40 +62,29 @@ def no_exchange():
         jax.lax.psum = psum
 
 
-def readings(workload, config, seed, candidates):
-    from bench import correct, data as bench_data, reference
+def readings(workload, config, seed, candidates=None, root=bench_run.ROOT):
+    """Each candidate's numbers at ``seed``; by default ``program`` and
+    every candidate of the configuration's model."""
+    from bench import models
 
+    model = models.load(config["model"], root)
+    if candidates is None:
+        candidates = ["program", *model.CANDIDATES]
     recipe = workload["recipe"]
-    sizes = bench_run.layer_sizes(config)
-    data = bench_data.make_dataset(config)
-    rounds = correct.COMPARED_ROUNDS
+    data = model.make_dataset(config)
     sched = None
     if recipe["scheduler"] == "async":  # the program's event schedule
-        sched = program_outs(workload, config, data, seed)
+        sched = program_outs(model, workload, config, data, seed)
     for cand in candidates:
         t0 = time.perf_counter()
-        decisions = None
         if cand == "program":
-            outs, decisions = sched or program_outs(workload, config, data, seed)
+            outs, decisions = sched or program_outs(model, workload, config, data, seed)
         elif cand == "no_exchange":
             with no_exchange():
-                outs, decisions = program_outs(workload, config, data, seed)
-        elif sched is not None:
-            outs, decisions = dict(sched[0]), sched[1]
-            acc, norm, _ = reference.run_async(
-                data, seed, recipe, sizes, outs, decisions, recipe["max_concurrency"],
-                precision="high" if cand == "control" else "highest",
-                fault=None if cand == "control" else cand,
-            )
-            outs.update(acc=acc, norm=norm)
+                outs, decisions = program_outs(model, workload, config, data, seed)
         else:
-            precision = "high" if cand == "control" else "highest"
-            fault = None if cand == "control" else cand
-            acc, sel, pms, norm = reference.run_free(
-                data, seed, recipe, sizes, rounds, precision=precision, fault=fault
-            )
-            outs = {"acc": acc, "sel": sel, "pms": pms, "norm": norm}
-        found = correct.numbers(outs, data, seed, recipe, sizes, decisions)
+            outs, decisions = model.candidate(cand, data, seed, recipe, config, sched)
+        found = model.numbers(outs, data, seed, recipe, config, decisions)
         yield {"candidate": cand, "seed": seed, **found,
                "seconds": time.perf_counter() - t0}
 
@@ -103,7 +94,7 @@ def main(argv=None):
     p = argparse.ArgumentParser(prog="bench.control")
     p.add_argument("--workload", required=True)
     p.add_argument("--seeds", required=True)
-    p.add_argument("--candidates", default="program,control,half_batch,answer,unchanged")
+    p.add_argument("--candidates", default=None)
     p.add_argument("--allow-cpu", action="store_true")
     args = p.parse_args(argv)
     import jax
@@ -113,7 +104,7 @@ def main(argv=None):
     if not args.allow_cpu and (dev[0].platform != "tpu" or len(dev) < entry["chips"]):
         raise SystemExit(f"bench.control: needs {entry['chips']} TPU chip(s)")
     bench_run.configure_jax(config)
-    candidates = args.candidates.split(",")
+    candidates = args.candidates.split(",") if args.candidates else None
     for seed in (int(s) for s in args.seeds.split(",")):
         for line in readings(workload, config, seed, candidates):
             print(json.dumps(line), flush=True)

@@ -4,10 +4,13 @@
 
 from the root of a checkout. The cell's entry in ``BENCHMARK.json`` names
 its workload file ``bench/workloads/<cell>.json``, which names its
-configuration ``bench/configs/<config>.json``. The run makes its data and
-weights from ``--seed``, drives ``repro.fl.run_federated`` unchanged with a
+configuration ``bench/configs/<config>.json``, whose ``model`` names the
+module ``bench/models/<model>.py`` that supplies the data, the program's
+configuration, the width check, the comparison with the model's reference
+and the work counts (``bench.models``). The run makes its data and weights
+from ``--seed``, drives ``repro.fl.run_federated`` unchanged with a
 ``bench.harness.Window`` as its recorder, checks the first rounds against
-``bench.reference`` and prints one JSON line last on standard output. With
+the model's reference and prints one JSON line last on standard output. With
 ``--trace 1`` it traces a steady span of the window and prints the cell's
 per-layer metrics (``bench/metrics/<metric>.py``) instead of its
 end-to-end ones. It refuses to run without a TPU.
@@ -59,34 +62,6 @@ def load_cell(name: str, root: Path = ROOT) -> tuple[dict, dict, dict, dict]:
     return bench, entry, workload, config
 
 
-def layer_sizes(config: dict) -> list[int]:
-    return [config["n_features"], *config["hidden"], config["n_classes"]]
-
-
-def fl_config(workload: dict, seed: int, rounds: int):
-    """The program's FLConfig for a workload's recipe."""
-    from repro.configs.base import (
-        CodecConfig, ExecutionConfig, PersonalizationConfig, SchedulerConfig,
-        SelectionConfig, TrainConfig,
-    )
-    from repro.fl.api import FLConfig
-
-    r = workload["recipe"]
-    sched = {k: r[k] for k in ("buffer_k", "max_concurrency", "staleness_fn",
-                               "staleness_exponent") if k in r}
-    return FLConfig(
-        selection=SelectionConfig(strategy=r["strategy"], decay=r["decay"]),
-        personalization=PersonalizationConfig(mode=r["personalization"]),
-        codec=CodecConfig(spec=r["codec"]),
-        train=TrainConfig(rounds=rounds, epochs=r["epochs"], batch_size=r["batch_size"],
-                          lr=r["lr"], seed=seed, remainder=r["remainder"]),
-        scheduler=SchedulerConfig(mode=r["scheduler"], **sched),
-        execution=ExecutionConfig(cohort_size=r["cohort_size"], eval_every=r["eval_every"],
-                                  scan_chunk=r["scan_chunk"],
-                                  cohort_devices=r["cohort_devices"]),
-    )
-
-
 def configure_jax(config: dict):
     import jax
 
@@ -116,19 +91,6 @@ class CacheCounter:
 
     def count(self, name: str, after: float = float("-inf")) -> int:
         return sum(1 for n, t in self.events if n == name and t > after)
-
-
-def check_widths(opened: dict, config: dict):
-    """The parameter count of each layer the program built against the
-    configuration's widths."""
-    import numpy as np
-
-    prefix = np.asarray(opened["clock"].params_prefix)
-    built = [int(x) for x in np.diff(prefix)]
-    s = layer_sizes(config)
-    want = [fi * fo + fo for fi, fo in zip(s[:-1], s[1:])]
-    if built != want:
-        raise SystemExit(f"program built layers of {built} parameters, configuration says {want}")
 
 
 class Tracer:
@@ -172,9 +134,10 @@ def run_cell(args, allow_cpu: bool = False, root: Path = ROOT) -> dict:
     numbers and their limits on standard error, last."""
     import jax
 
-    from bench import correct, data as bench_data, harness, peaks
+    from bench import correct, harness, models, peaks
 
     bench, entry, workload, config = load_cell(args.workload, root)
+    model = models.load(config["model"], root)
     devices = jax.devices()
     chips = entry["chips"]
     if not allow_cpu and (devices[0].platform != "tpu" or len(devices) < chips):
@@ -188,9 +151,8 @@ def run_cell(args, allow_cpu: bool = False, root: Path = ROOT) -> dict:
 
     from repro.fl.engine import run_federated
 
-    data = bench_data.make_dataset(config)
-    rounds = 10 ** 9
-    cfg = fl_config(workload, args.seed, rounds)
+    data = model.make_dataset(config)
+    cfg = model.fl_config(workload, config, args.seed, 10 ** 9)
     tracer = None
     if args.trace:
         tracer = Tracer(CACHE / "trace" / args.workload, workload["trace_seconds"])
@@ -203,7 +165,7 @@ def run_cell(args, allow_cpu: bool = False, root: Path = ROOT) -> dict:
         raise SystemExit("run_federated returned before the window closed")
     if tracer is not None and tracer.t1 is None:
         tracer.stop(window)
-    check_widths(window.opened, config)
+    model.check_widths(window.opened, config)
     used = devices[:chips]
     stats = [d.memory_stats() or {} for d in used]
     memory_peak = max((s.get("peak_bytes_in_use", 0) for s in stats), default=0)
@@ -218,8 +180,7 @@ def run_cell(args, allow_cpu: bool = False, root: Path = ROOT) -> dict:
           file=sys.stderr, flush=True)
 
     recipe = workload["recipe"]
-    sizes = layer_sizes(config)
-    found = correct.numbers(window.early_outs, data, args.seed, recipe, sizes, window.decisions)
+    found = model.numbers(window.early_outs, data, args.seed, recipe, config, window.decisions)
     limits = workload["limits"]
     ok = correct.judge(found, limits)
 
@@ -242,7 +203,7 @@ def run_cell(args, allow_cpu: bool = False, root: Path = ROOT) -> dict:
     if args.trace:
         from bench import metrics as metric_readers, trace_reduce
 
-        facts = trace_reduce.facts(tracer, window, data, config, recipe, peak, chips)
+        facts = trace_reduce.facts(tracer, window, model, data, config, recipe, peak, chips)
         result["device"]["busy_s"] = facts.busy_s
         result["device"]["window_s"] = facts.window_s
         for m in cell_metrics("per_layer"):

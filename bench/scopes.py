@@ -18,6 +18,8 @@ inside ``fl.round``, ``fl.event`` or ``fl.chunk`` with no phase is
 instant of the window that some op covers goes to the innermost op
 running then (the one that started last), so a ``while`` and the ops of
 its body count once, and the phases' times add up to the busy time.
+``scope_ms`` reads any scope by name, a mechanism's own nested in a phase
+included: an instant counts for every scope on its op's path.
 
 Facts do not carry the trace's path: ``for_facts`` takes the newest
 ``*.xplane.pb`` under ``.bench_cache/trace/`` and accepts it only if its
@@ -266,18 +268,19 @@ def instruction_scopes(hlo_proto) -> dict:
 
 def reduce(trace: dict) -> dict:
     """Per device: each phase's time inside the window (``phase_ns``, a
-    list per kind of ``KINDS``), the busy time, and whether any op there
-    carries an ``fl.`` scope."""
+    list per kind of ``KINDS``), each scope's (``scope_ns``, a list per
+    component of any op's scope path), the busy time, and whether any op
+    there carries an ``fl.`` scope."""
     windows = [(s, e) for n, s, e in annotations(trace) if n == WINDOW]
     if not windows:
         raise ValueError("trace has no bench.window annotation")
     w0, w1 = windows[0]
     phase_ns = {k: [] for k in KINDS}
-    busy_ns, scoped = [], False
+    by_scope, busy_ns, scoped = [], [], False
     for pl in trace["planes"]:
         if not DEVICE_PLANE.match(pl["name"]):
             continue
-        ops = []
+        ops, paths = [], []
         for ln in pl["lines"]:
             if ln["name"] != OPS_LINE:
                 continue
@@ -285,12 +288,23 @@ def reduce(trace: dict) -> dict:
                 s0, e0 = max(s, w0), min(s + d, w1)
                 if e0 > s0:
                     ops.append((s0, e0, phase_of(scope)))
+                    paths.append((s0, e0, scope))
                     scoped = scoped or bool(scope)
         acc = _innermost(ops)
         for k in KINDS:
             phase_ns[k].append(acc.get(k, 0.0))
         busy_ns.append(sum(acc.values()))
-    return {"window_ns": w1 - w0, "busy_ns": busy_ns, "phase_ns": phase_ns, "scoped": scoped}
+        # the same instants, each counted for every scope on its innermost
+        # op's path
+        per_scope: dict[str, float] = {}
+        for path, ns in _innermost(paths).items():
+            for name in set(path.split("/")) - {""}:
+                per_scope[name] = per_scope.get(name, 0.0) + ns
+        by_scope.append(per_scope)
+    names = set().union(*by_scope)
+    scope_ns = {n: [d.get(n, 0.0) for d in by_scope] for n in sorted(names)}
+    return {"window_ns": w1 - w0, "busy_ns": busy_ns, "phase_ns": phase_ns,
+            "scope_ns": scope_ns, "scoped": scoped}
 
 
 def _innermost(ops) -> dict:
@@ -353,6 +367,23 @@ def phase_ms(facts, phase: str, directory=None) -> float | None:
     if red is None:
         return None
     ns = red["phase_ns"][phase]
+    return 1e-6 * (sum(ns) / len(ns)) / facts.rounds
+
+
+def scope_ms(facts, name: str, directory=None) -> float | None:
+    """Device milliseconds a round (or event), mean over chips, of the ops
+    whose scope path holds ``name`` as a whole component: a phase's scope
+    such as ``fl.train``, or a mechanism's scope nested in one, such as
+    ``moe.dispatch`` inside ``fl.train`` (only ops inside some ``fl.``
+    scope keep a path). Each covered instant goes to the innermost op, as
+    for the phases, and counts for every scope on that op's path, so
+    nested scopes overlap. None where no op of the window is in ``name``."""
+    if facts.rounds == 0:
+        return None
+    red = for_facts(facts, directory)
+    if red is None or name not in red["scope_ns"]:
+        return None
+    ns = red["scope_ns"][name]
     return 1e-6 * (sum(ns) / len(ns)) / facts.rounds
 
 

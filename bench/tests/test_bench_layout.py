@@ -10,6 +10,7 @@ from pathlib import Path
 import pytest
 
 from bench import metrics as metric_readers
+from bench import models
 from bench import run as bench_run
 
 ROOT = bench_run.ROOT
@@ -86,3 +87,13 @@ def test_adding_a_config_a_cell_and_a_metric_needs_only_files(tmp_path):
         rounds = 7
 
     assert metric_readers.read("new_metric", Facts(), root) == 7.0
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_every_configuration_names_a_model_module(entry):
+    config = json.loads((ROOT / entry["file"]).read_text())
+    model = models.load(config["model"], ROOT)
+    for name in ("make_dataset", "data_facts", "fl_config", "check_widths", "numbers",
+                 "candidate"):
+        assert callable(getattr(model, name)), name
+    assert model.CANDIDATES

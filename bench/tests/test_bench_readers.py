@@ -1,18 +1,21 @@
 """Per-layer readers on hand-made reductions: the codec's roofline finds
 its kernels by name and refuses a count that is not 16 a round; the
 exposed collective share averages the chips and reads nothing without a
-collective."""
+collective; the whole step's mfu is the model's work over the window at
+the chips' peak."""
 
 from types import SimpleNamespace
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from bench import flops
 from bench import metrics as metric_readers
-from bench import peaks
+from bench import models, peaks
 
 ROOT = Path(__file__).resolve().parents[2]
+HAR = models.load("har-mlp", ROOT)
 CONFIG = {"n_features": 561, "hidden": [256, 256, 256], "n_classes": 6, "n_clients": 30}
 QUANT = "%vmap_jit_quantize__.{} = (s8[30,128,512]{{2,1,0}}, f32[30,128,1]{{2,1,0}}) custom-call(%slice)"
 DEQUANT = "%vmap_jit_dequantize__.{} = f32[30,128,512]{{2,1,0}} custom-call(%q, %s)"
@@ -26,7 +29,7 @@ def _facts(ops, rounds=2, chips=1, **red):
                "op_ns": {k: ns for k, (_, _, ns) in ops.items()}, **red}
     return SimpleNamespace(reduced=reduced, rounds=rounds, chips=chips, config=CONFIG,
                            recipe={"codec": "int8"}, peak=peaks.PEAKS["TPU v5 lite"],
-                           window_s=1.0)
+                           window_s=1.0, model=HAR)
 
 
 def _codec_ops(rounds, kernel_ns):
@@ -62,3 +65,17 @@ def test_exposed_collective_share_is_the_mean_over_chips():
     assert metric_readers.read("exposed_collective_share", facts, ROOT) == pytest.approx(10.0)
     none = _facts({}, collective_ns=[0.0], exposed_collective_ns=[0.0])
     assert metric_readers.read("exposed_collective_share", none, ROOT) is None
+
+
+def test_round_mfu_is_the_models_work_over_the_window_at_peak():
+    sizes = [561, 256, 256, 256, 6]
+    sel = np.array([[True, False, True], [False, True, False]])
+    facts = _facts({}, chips=2)
+    facts.__dict__.update(sel=sel, n_train_valid=np.array([200, 246, 100]), n_train_rows=246,
+                          n_test_valid=np.array([81, 70, 60]),
+                          recipe={"batch_size": 32, "epochs": 2})
+    work = flops.round_flops(sizes, sel, facts.n_train_valid, 246, facts.n_test_valid, 32, 2)
+    value = metric_readers.read("round_mfu", facts, ROOT)
+    assert value == pytest.approx(100.0 * work / (1.0 * 197e12 * 2))
+    assert metric_readers.read("round_mfu.short", facts, ROOT) == value
+    assert metric_readers.read("event_mfu", facts, ROOT) == value

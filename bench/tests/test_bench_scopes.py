@@ -202,3 +202,30 @@ def test_each_op_is_looked_up_in_the_program_it_ran_in():
     assert scopes._lookup(by_module, "jit_b(2)", "fusion.1") == "fl.round/fl.eval/dot"
     # a name two programs share cannot be told apart without the program
     assert scopes._lookup(by_module, None, "fusion.1") == ""
+
+
+NESTED = json.loads((Path(__file__).parent / "scope_nested_fixture.json").read_text())
+
+
+def test_scope_ms_reads_a_mechanism_scope_nested_in_a_phase(traced):
+    """One op in ``fl.train/moe.dispatch`` (1000 ns), one in ``fl.train``
+    alone (2000 ns), one in ``fl.eval`` (500 ns), over 2 rounds."""
+    traced["value"] = NESTED
+    facts = _facts(rounds=2)
+    assert scopes.scope_ms(facts, "moe.dispatch") == pytest.approx(0.0005)
+    assert scopes.scope_ms(facts, "fl.train") == pytest.approx(0.0015)
+    assert scopes.scope_ms(facts, "fl.round") == pytest.approx(0.00175)
+    # the phase split reads as before: train holds both ops
+    assert scopes.reduce(NESTED)["phase_ns"]["train"] == [3000]
+    assert scopes.phase_ms(facts, "train") == scopes.scope_ms(facts, "fl.train")
+    # a whole component only, and nothing for a scope no op is in
+    assert scopes.scope_ms(facts, "dispatch") is None
+    assert scopes.scope_ms(facts, "moe.combine") is None
+
+
+def test_scope_ms_of_a_phase_on_the_first_fixture(traced):
+    facts = _facts(rounds=2)
+    # fl.train holds no other phase's op there, so it reads as the phase
+    assert scopes.scope_ms(facts, "fl.train") == scopes.phase_ms(facts, "train")
+    # a chunk's while counts for fl.chunk around every op inside it
+    assert scopes.reduce(FIXTURE)["scope_ns"]["fl.chunk"] == [7000, 10000]

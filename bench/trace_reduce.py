@@ -180,19 +180,24 @@ class Facts:
     recipe: dict
     peak: dict | None
     chips: int
-    n_train_valid: np.ndarray
-    n_train_rows: int
-    n_test_valid: np.ndarray
+    # the model's ``data_facts``, in its own unit (samples for har-mlp)
+    n_train_valid: np.ndarray  # (C,) valid train samples
+    n_train_rows: int          # rows of the train slab
+    n_test_valid: np.ndarray   # (C,) valid test samples
     breakdown: dict
+    model: object            # the configuration's model module (``bench.models``)
+    data: object             # what the run handed ``run_federated``
 
 
-def facts(tracer, window, data, config, recipe, peak, chips) -> Facts:
+def facts(tracer, window, model, data, config, recipe, peak, chips) -> Facts:
     path = find_trace(tracer.dir)
     if path is None:
         raise SystemExit(f"no trace written under {tracer.dir}")
     red = reduce(load(path))
+    counts = model.data_facts(data)
     hooks = window.hooks[tracer.first_hook:tracer.last_hook]
-    sel = np.concatenate([h[2] for h in hooks]) if hooks else np.zeros((0, data.n_clients), bool)
+    sel = (np.concatenate([h[2] for h in hooks]) if hooks
+           else np.zeros((0, len(counts["n_train_valid"])), bool))
     phase_s = {}
     for name, s, e in window.profiler.spans:
         o = min(e, tracer.t1) - max(s, tracer.t0)
@@ -212,11 +217,11 @@ def facts(tracer, window, data, config, recipe, peak, chips) -> Facts:
         recipe=recipe,
         peak=peak,
         chips=chips,
-        n_train_valid=np.asarray(data.m_train).sum(axis=1),
-        n_train_rows=int(data.x_train.shape[1]),
-        n_test_valid=np.asarray(data.m_test).sum(axis=1),
+        **counts,
         breakdown={
             "device_ops": [[k, v * 1e-9] for k, v in top_ops],
             "idle_gaps": [[k, v * 1e-9] for k, v in red["gaps"][:10]],
         },
+        model=model,
+        data=data,
     )
