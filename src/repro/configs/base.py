@@ -22,6 +22,23 @@ def round_up(x: int, m: int) -> int:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN context extension of RoPE (arXiv:2309.00071), as DeepSeek-V2's
+    ``rope_scaling`` states it: rotary frequencies past the ``beta_fast``
+    correction dimension are divided by ``factor``, those before
+    ``beta_slow`` keep theirs, with a linear ramp between; the softmax scale
+    gains ``yarn_mscale(factor, mscale_all_dim) ** 2`` and the rotation
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense | moe | ssm | hybrid | vlm | audio
@@ -39,6 +56,8 @@ class ModelConfig:
     n_shared_experts: int = 0
     top_k: int = 0
     d_ff_expert: int = 0             # per-expert FFN width (fine-grained MoE)
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0  # multiplies the routed experts' sum
     moe_every: int = 1               # MoE at layer indices where idx % moe_every == moe_offset
     moe_offset: int = 0
     first_dense: int = 0             # deepseek: leading dense layers
@@ -49,7 +68,8 @@ class ModelConfig:
     qk_rope_dim: int = 64            # MLA decoupled-RoPE dim
     qk_nope_dim: int = 128           # MLA content dim per head
     v_head_dim: int = 128            # MLA value dim per head
-    rope_variant: str = "full"       # full | half (chatglm 2d) | mrope
+    rope_variant: str = "full"       # full | half (chatglm 2d) | mrope | yarn
+    rope_scaling: Optional[YarnScaling] = None  # rope_variant == "yarn"
     mrope_sections: tuple = (16, 24, 24)  # qwen2-vl: t/h/w of head_dim//2
     sliding_window: int = 0          # >0: sliding-window attention (long_500k variant)
 
@@ -132,6 +152,7 @@ class ModelConfig:
                 qd = self.qk_nope_dim + self.qk_rope_dim
                 total += d * self.n_heads * qd          # W_q
                 total += d * (r + self.qk_rope_dim)     # W_dkv + rope
+                total += r                              # latent RMSNorm
                 total += r * self.n_heads * (self.qk_nope_dim + self.v_head_dim)
                 total += self.n_heads * self.v_head_dim * d  # W_o
             elif self.attn_type == "gqa":

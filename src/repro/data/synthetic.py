@@ -26,10 +26,16 @@ import numpy as np
 
 @dataclasses.dataclass
 class FederatedDataset:
-    """Stacked federated dataset (leading axis = clients)."""
+    """Stacked federated dataset (leading axis = clients).
 
-    x_train: np.ndarray  # (C, N_tr, F) float32
-    y_train: np.ndarray  # (C, N_tr) int32
+    A row is a sample with one label (``x`` (C, N, F) float features, ``y``
+    (C, N) classes), or a token sequence with a label per position (``x``
+    (C, N, S) int32 ids, ``y`` (C, N, S) int32 next-token targets, the mask
+    per sequence); ``n_samples`` counts labels, so tokens for the latter.
+    """
+
+    x_train: np.ndarray  # (C, N_tr, F) float32, or (C, N_tr, S) int32 ids
+    y_train: np.ndarray  # (C, N_tr) int32, or (C, N_tr, S) int32 targets
     m_train: np.ndarray  # (C, N_tr) bool — padding mask
     x_test: np.ndarray   # (C, N_te, F)
     y_test: np.ndarray   # (C, N_te)
@@ -47,8 +53,10 @@ class FederatedDataset:
 
     @property
     def n_samples(self) -> np.ndarray:
-        """(C,) true (unpadded) train sample counts |d_i|."""
-        return self.m_train.sum(axis=1).astype(np.int32)
+        """(C,) true (unpadded) train sample counts |d_i|: labels, so a
+        sequence counts its tokens."""
+        per_row = int(np.prod(self.y_train.shape[2:], dtype=np.int64))
+        return (self.m_train.sum(axis=1) * per_row).astype(np.int32)
 
     def shard(self, idx: np.ndarray):
         """(K, ...) data rows for client ids ``idx`` — one cohort's slabs.

@@ -8,6 +8,7 @@ from repro.fl.api import (
     ExecutionConfig,
     FaultConfig,
     FLConfig,
+    FLModel,
     PersonalizationConfig,
     RoundPipeline,
     SchedulerConfig,
@@ -24,6 +25,7 @@ from repro.fl.shard import build_sharded_round_step
 
 __all__ = [
     "FLConfig",
+    "FLModel",
     "SelectionConfig",
     "PersonalizationConfig",
     "CodecConfig",

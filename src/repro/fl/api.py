@@ -64,6 +64,7 @@ from repro.models.mlp import mlp_accuracy, mlp_loss
 
 __all__ = [
     "FLConfig",
+    "FLModel",
     "SelectionConfig",
     "PersonalizationConfig",
     "CodecConfig",
@@ -129,6 +130,37 @@ _GROUP_TYPES = {
 }
 
 
+@dataclasses.dataclass(frozen=True)
+class FLModel:
+    """The model a federated run trains, when it is not the paper's MLP.
+
+    ``init(rng)`` builds the layered model (a list of per-layer pytrees:
+    what layer sharing and DLD count); ``loss(params, x, y, m)`` and
+    ``accuracy(params, x, y, m)`` score one client's batch of rows (``m``
+    the rows' validity mask). ``evaluate(params, x, y, m)``, where given,
+    returns ``(accuracy, loss, counts)`` from one pass, and the evaluator
+    uses it in their place; ``counts`` names each count it returns with how
+    the round folds it over the clients, ``"sum"`` or ``"max"``. Each count
+    is a per-round record of the history (``FLHistory.counts``).
+    """
+
+    init: Callable
+    loss: Callable
+    accuracy: Callable
+    evaluate: Callable | None = None
+    counts: tuple = ()            # ((name, "sum" | "max"), ...)
+
+
+def model_functions(cfg: "FLConfig", init_fn, loss_fn, acc_fn):
+    """``(init_fn, loss_fn, acc_fn, model)`` of a run: the config's
+    ``model`` where it names one, else the functions given (the MLP's by
+    default; ``init_fn=None`` builds it from the data's widths)."""
+    m = cfg.model
+    if m is None:
+        return init_fn, loss_fn, acc_fn, None
+    return m.init, m.loss, m.accuracy, m
+
+
 @dataclasses.dataclass(frozen=True, init=False)
 class FLConfig:
     """Federated experiment config: seven nested validated sub-configs.
@@ -137,7 +169,8 @@ class FLConfig:
     or the seed's flat kwargs (``strategy="oort", fraction=0.5, rounds=30,
     codec="int8", cohort_size=64, dropout_rate=0.3``) — but not both forms
     for the same group. The seed's flat attributes (``cfg.strategy``,
-    ``cfg.rounds``, ...) remain readable.
+    ``cfg.rounds``, ...) remain readable. ``model`` (an ``FLModel``) names
+    the model the run trains; None (default) is the paper's MLP.
     """
 
     selection: SelectionConfig
@@ -147,10 +180,11 @@ class FLConfig:
     scheduler: SchedulerConfig
     execution: ExecutionConfig
     faults: FaultConfig
+    model: FLModel | None
 
     def __init__(self, selection=None, personalization=None, codec=None,
                  train=None, scheduler=None, execution=None, faults=None,
-                 **flat):
+                 model=None, **flat):
         # string conveniences on the group params themselves: the seed's
         # FLConfig(personalization="dld", codec="int8") spelled the mode/spec
         # directly, so route strings into the flat namespace
@@ -191,6 +225,9 @@ class FLConfig:
                 object.__setattr__(self, group, given[group])
             else:
                 object.__setattr__(self, group, cls(**grouped[group]))
+        if model is not None and not isinstance(model, FLModel):
+            raise TypeError(f"model must be an FLModel, got {type(model).__name__}")
+        object.__setattr__(self, "model", model)
 
     # --- flat read access (seed compatibility) -----------------------------
     @property
@@ -395,8 +432,10 @@ def build_env(
     seed: int,
     loss_fn: Callable = mlp_loss,
     acc_fn: Callable = mlp_accuracy,
+    model: FLModel | None = None,
 ) -> phases.RoundEnv:
-    """Device-resident static environment for the round phases."""
+    """Device-resident static environment for the round phases; ``model``
+    adds its one-pass ``evaluate`` and its counts."""
     return phases.RoundEnv(
         x_tr=jnp.asarray(data.x_train),
         y_tr=jnp.asarray(data.y_train),
@@ -413,6 +452,8 @@ def build_env(
         loss_fn=loss_fn,
         acc_fn=acc_fn,
         population=data.n_clients,
+        eval_fn=model.evaluate if model is not None else None,
+        count_ops=model.counts if model is not None else (),
     )
 
 
@@ -663,6 +704,8 @@ def build_round_step(
             # transmitted update failed validation)
             "rejected": n_rejected,
         }
+        if pctx.counts is not None:
+            out["counts"] = phases.fold_counts(pctx.counts, env.count_ops)
         return new_state, out
 
     def round_step(state: RoundState, t: jnp.ndarray):

@@ -74,6 +74,7 @@ from repro.fl.api import (
     RoundPipeline,
     build_env,
     build_round_step,
+    model_functions,
     pipeline_from_config,
 )
 from repro.models.mlp import mlp_accuracy, mlp_loss
@@ -116,6 +117,11 @@ class FLHistory(NamedTuple):
                                    # before aggregation; None only on
                                    # history producers that predate the
                                    # guard (identically 0 on healthy runs).
+    counts: dict | None = None
+                                   # {name: (T,)} the model's per-round counts
+                                   # (``FLModel.counts``, from the evaluation
+                                   # pass, folded over the clients); None for
+                                   # a model without counts, such as the MLP.
 
 
 def make_round_step(
@@ -133,7 +139,8 @@ def make_round_step(
     cohort-sharded variant (repro.fl.shard): same signature, compute
     phases shard_mapped K/D lanes per device over a ``cohort`` mesh."""
     pipeline = pipeline or pipeline_from_config(cfg)
-    env = build_env(data, cfg.seed, loss_fn=loss_fn, acc_fn=acc_fn)
+    _, loss_fn, acc_fn, model = model_functions(cfg, None, loss_fn, acc_fn)
+    env = build_env(data, cfg.seed, loss_fn=loss_fn, acc_fn=acc_fn, model=model)
     return build_round_step(env, pipeline, cfg.execution)
 
 
@@ -154,6 +161,10 @@ def run_federated(
 ) -> FLHistory:
     """Run ``cfg.rounds`` federated rounds (sync) or aggregation events
     (async) under the configured scheduler; returns host-side history.
+
+    The model is ``cfg.model`` (an ``FLModel``) where the config names
+    one; otherwise ``init_fn``/``loss_fn``/``acc_fn``, by default the
+    paper's MLP built for the data's widths.
 
     ``client_delay`` is an optional (C,) multiplicative heterogeneity lane
     for the simulated clock (stragglers); by default it is derived from

@@ -140,6 +140,8 @@ class RoundEnv:
     loss_fn: Callable
     acc_fn: Callable
     population: int = 0      # true population C; 0 -> n_clients
+    eval_fn: Callable | None = None  # (params, x, y, m) -> (acc, loss, counts)
+    count_ops: tuple = ()    # ((count name, "sum" | "max"), ...) of eval_fn
 
     @property
     def pop(self) -> int:
@@ -237,6 +239,15 @@ class RoundContext(NamedTuple):
     merge_weight: Any = None      # Aggregator — (lanes,) staleness discount
                                   # each landing update was merged with
                                   # (observability signal; no phase reads it)
+    counts: Any = None            # Evaluator — {name: (lanes,)} the model's
+                                  # counts (env.eval_fn), None without them
+
+
+def fold_counts(counts: dict, ops) -> dict:
+    """Fold per-lane counts ``{name: (lanes,)}`` into one value each, by
+    the model's ``ops`` (``((name, "sum" | "max"), ...)``)."""
+    fold = {"sum": jnp.sum, "max": jnp.max}
+    return {name: fold[op](counts[name]) for name, op in ops}
 
 
 def _stack_clients(params, n_clients: int):
@@ -371,7 +382,7 @@ def _batched(x, y, m, batch_size: int, remainder: str = "drop"):
         if take > n:
             pad = take - n
             x = jnp.concatenate([x, jnp.zeros((pad, *x.shape[1:]), x.dtype)])
-            y = jnp.concatenate([y, jnp.zeros((pad,), y.dtype)])
+            y = jnp.concatenate([y, jnp.zeros((pad, *y.shape[1:]), y.dtype)])
             m = jnp.concatenate([m, jnp.zeros((pad,), m.dtype)])
     else:
         nb = max(1, n // batch_size)
@@ -381,7 +392,7 @@ def _batched(x, y, m, batch_size: int, remainder: str = "drop"):
         x, y, m = x[:take], y[:take], m[:take]
     return (
         x.reshape(nb, batch_size, *x.shape[1:]),
-        y.reshape(nb, batch_size),
+        y.reshape(nb, batch_size, *y.shape[1:]),
         m.reshape(nb, batch_size),
     )
 
@@ -798,27 +809,34 @@ class DistributedEvaluator(Evaluator):
     def evaluate(self, ctx, env, model_fn=None):
         def fresh(_):
             model = model_fn() if model_fn is not None else ctx.eval_model
+            if env.eval_fn is not None:  # the model's one pass; counts as float32
+                acc, loss, counts = jax.vmap(env.eval_fn)(model, env.x_te, env.y_te, env.m_te)
+                return acc, loss, {k: v.astype(jnp.float32) for k, v in counts.items()}
             acc = jax.vmap(lambda p, x, y, m: env.acc_fn(p, x, y, m))(
                 model, env.x_te, env.y_te, env.m_te
             )
             loss = jax.vmap(lambda p, x, y, m: env.loss_fn(p, x, y, m))(
                 model, env.x_te, env.y_te, env.m_te
             )
-            return acc, loss
+            return acc, loss, None
 
         if self.eval_every == 1:
-            acc, loss = fresh(None)
+            acc, loss, counts = fresh(None)
         else:
             zeros = jnp.zeros((env.n_clients,), jnp.float32)
             prev_acc = ctx.prev_accuracy if ctx.prev_accuracy is not None else zeros
             prev_loss = ctx.prev_loss if ctx.prev_loss is not None else zeros
-            acc, loss = jax.lax.cond(
-                (ctx.t % self.eval_every) == 0,
-                fresh,
-                lambda _: (prev_acc, prev_loss),
-                None,
+
+            def carried(_):  # a skipped round counts nothing
+                counts = None
+                if env.eval_fn is not None:
+                    counts = {name: zeros for name, _ in env.count_ops}
+                return prev_acc, prev_loss, counts
+
+            acc, loss, counts = jax.lax.cond(
+                (ctx.t % self.eval_every) == 0, fresh, carried, None
             )
-        return ctx._replace(accuracy=acc, loss=loss)
+        return ctx._replace(accuracy=acc, loss=loss, counts=counts)
 
 
 # ---------------------------------------------------------------------------

@@ -68,7 +68,13 @@ from repro.core.metrics import (
     edge_partition,
 )
 from repro.fl import phases
-from repro.fl.api import FLConfig, RoundPipeline, _tree_where, pipeline_from_config
+from repro.fl.api import (
+    FLConfig,
+    RoundPipeline,
+    _tree_where,
+    model_functions,
+    pipeline_from_config,
+)
 from repro.fl.faults import apply_corruption, compile_fault_plan
 from repro.fl.sched import (
     ClientClock,
@@ -258,6 +264,7 @@ class _HostSetup:
         self.comm = comm or CommModel()
         rng = jax.random.PRNGKey(cfg.seed)
         r_init, self.r_loop = jax.random.split(rng)
+        init_fn, loss_fn, acc_fn, _ = model_functions(cfg, init_fn, loss_fn, acc_fn)
         if init_fn is None:
             init_fn = lambda r: init_mlp(r, data.n_features, data.n_classes)
         self.g0 = init_fn(r_init)

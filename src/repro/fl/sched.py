@@ -97,10 +97,12 @@ from repro.fl.api import (
     build_chunk_step,
     build_env,
     build_round_step,
+    model_functions,
     pipeline_from_config,
 )
 from repro.fl.cohort import tree_scatter, tree_take
 from repro.fl.faults import compile_fault_plan
+from repro.fl.shard import lane_placement
 from repro.models.mlp import init_mlp, mlp_accuracy, mlp_loss
 from repro.obs.profile import phase_timer
 from repro.obs.record import format_async_progress, format_sync_progress
@@ -140,7 +142,8 @@ class ClientClock:
     """
 
     comm: CommModel
-    n_samples: np.ndarray      # (C,) float64 — |d_i|
+    n_samples: np.ndarray      # (C,) float64 — |d_i|, in labels: samples, or
+                               # tokens for sequence data (FederatedDataset)
     epochs: int
     params_prefix: np.ndarray  # (L+1,) — params in the first k layers
     wire_prefix: np.ndarray    # (L+1,) float64 — codec uplink wire bytes
@@ -205,8 +208,8 @@ class ClientClock:
 
     def round_flops(self, pms: np.ndarray, cids: np.ndarray | None = None) -> np.ndarray:
         """Local-training FLOPs per client at share depth ``pms`` — the one
-        place the compute model (fwd+bwd ~ 6 * params * samples * epochs)
-        lives; ``durations`` and the schedulers' accounting both use it.
+        place the compute model (fwd+bwd ~ 6 * params * samples * epochs,
+        a sample being a token for sequence data) lives; ``durations`` and the schedulers' accounting both use it.
         Broadcasts like ``shared_params`` (``(T, C)`` chunk batches).
         ``cids`` restricts to a client subset: ``pms`` then carries those
         clients' depths and the sample lane is row-gathered to match."""
@@ -322,22 +325,28 @@ class _RunSetup:
     pms0: int
     n_layers: int
     r_loop: jax.Array
+    vectors: Any = None  # where the (C,) vectors live (one client a device)
 
     def initial_state(self) -> RoundState:
         """Round 0's carried state: every client selected, nothing
         observed yet."""
         c = self.env.n_clients
-        return RoundState(
-            global_params=self.g0,
-            local_params=self.loc0,
+        vectors = dict(
             accuracy=jnp.zeros((c,)),
             select=jnp.ones((c,), bool),
             pms=jnp.full((c,), self.pms0, jnp.int32),
             rng=self.r_loop,
-            residual=self.residual0,
             participation=jnp.zeros((c,), jnp.int32),
             loss=jnp.zeros((c,), jnp.float32),
             update_norm=jnp.zeros((c,), jnp.float32),
+        )
+        if self.vectors is not None:
+            vectors = jax.device_put(vectors, self.vectors)
+        return RoundState(
+            global_params=self.g0,
+            local_params=self.loc0,
+            residual=self.residual0,
+            **vectors,
         )
 
 
@@ -357,6 +366,7 @@ def _setup_run(
     comm = comm or CommModel()
     rng = jax.random.PRNGKey(cfg.seed)
     r_init, r_loop = jax.random.split(rng)
+    init_fn, loss_fn, acc_fn, model = model_functions(cfg, init_fn, loss_fn, acc_fn)
     if init_fn is None:
         init_fn = lambda r: init_mlp(r, data.n_features, data.n_classes)
     g0 = init_fn(r_init)
@@ -364,27 +374,34 @@ def _setup_run(
     # every client starts from the same init (paper: server broadcasts w(0));
     # stateless personalizers never read per-client locals, so the O(C)
     # model carry is skipped entirely
-    loc0 = (
-        jax.tree.map(
-            lambda gl: jnp.broadcast_to(gl, (data.n_clients,) + gl.shape), g0
+    place = lane_placement(cfg.execution, data.n_clients)
+    if place is not None:
+        # a sharded run with one client a device: its slabs built there
+        g0, loc0, residual0 = place(
+            g0, pipeline.personalizer.stateful, pipeline.transmit.lossy
         )
-        if pipeline.personalizer.stateful
-        else None
-    )
-    residual0 = (
-        jax.tree.map(
-            lambda gl: jnp.zeros((data.n_clients,) + gl.shape, gl.dtype), g0
+    else:
+        loc0 = (
+            jax.tree.map(
+                lambda gl: jnp.broadcast_to(gl, (data.n_clients,) + gl.shape), g0
+            )
+            if pipeline.personalizer.stateful
+            else None
         )
-        if pipeline.transmit.lossy
-        else None
-    )
+        residual0 = (
+            jax.tree.map(
+                lambda gl: jnp.zeros((data.n_clients,) + gl.shape, gl.dtype), g0
+            )
+            if pipeline.transmit.lossy
+            else None
+        )
     # Algorithm 1: round 1 selects ALL clients; the shared piece is cut from
     # the first round in PMS mode (DLD starts full: A=0 <= 0.25 -> all layers)
     pms0 = cfg.pms_layers if cfg.personalization.mode == "pms" else n_layers
     return _RunSetup(
         pipeline=pipeline,
         comm=comm,
-        env=build_env(data, cfg.seed, loss_fn=loss_fn, acc_fn=acc_fn),
+        env=build_env(data, cfg.seed, loss_fn=loss_fn, acc_fn=acc_fn, model=model),
         clock=ClientClock.build(g0, pipeline.transmit.codec, data, cfg, comm, client_delay),
         g0=g0,
         loc0=loc0,
@@ -392,6 +409,7 @@ def _setup_run(
         pms0=pms0,
         n_layers=n_layers,
         r_loop=r_loop,
+        vectors=place.replicated if place is not None else None,
     )
 
 
@@ -532,7 +550,9 @@ class SyncScheduler:
         # body, and the default path's DEVICE trajectory must stay
         # bit-for-bit the seed loop (host round-time accounting is the
         # float64 vectorized pass on every path — see the class docstring)
-        per_round = jax.jit(round_step) if chunk <= 1 else None
+        # the step donates the carried state, as the fused chunk does: the
+        # loop never reads a round's input state after dispatching it
+        per_round = jax.jit(round_step, donate_argnums=0) if chunk <= 1 else None
         chunk_steps: dict[int, Callable] = {}  # length -> fused executable
         lanes = cfg.execution.resolved_cohort(data.n_clients)
         delay = None if clock.uniform else clock.delay
@@ -552,6 +572,7 @@ class SyncScheduler:
         edge_hist: list[np.ndarray] = []
         accs, sel_hist, tx_hist, pms_hist, times, wire_hist = [], [], [], [], [], []
         rejected_hist: list[np.ndarray] = []
+        counts_hist: list[dict] = []  # the model's per-round counts, if any
         start = 0
         if resume_from is not None:
             # latest snapshot: RoundState through repro.checkpoint (rng
@@ -607,7 +628,7 @@ class SyncScheduler:
                     state, out = per_round(state, jnp.asarray(t0), *extra)
                 with phase_timer(prof, "device_get"):
                     outs = jax.device_get(out)
-                outs = {k: np.asarray(v)[None] for k, v in outs.items()}
+                outs = jax.tree.map(lambda v: np.asarray(v)[None], outs)
             else:
                 step = chunk_steps.get(n)
                 if step is None:  # one trace per distinct length (body + tail)
@@ -674,6 +695,10 @@ class SyncScheduler:
                 pms_hist.append(pms)
                 tx_hist.append(np.asarray(outs["tx_params"], np.float64))
                 wire_hist.append(wire.sum(axis=1))
+                counts = None
+                if "counts" in outs:
+                    counts = {k: np.asarray(v) for k, v in outs["counts"].items()}
+                    counts_hist.append(counts)
             with phase_timer(prof, "record"):
                 if recorder is not None:
                     # one vectorized append per chunk, straight off the stacked
@@ -688,6 +713,7 @@ class SyncScheduler:
                             if n_dropped is not None
                             else None
                         ),
+                        **({"counts": counts} if counts is not None else {}),
                     )
             if progress:
                 for i in _progress_rows(t0, n, chunk, cfg.rounds):
@@ -734,6 +760,11 @@ class SyncScheduler:
             in_flight=np.full(times.shape, lanes, np.int64),
             tx_edge_bytes=np.concatenate(edge_hist) if n_edges >= 1 else None,
             rejected_updates=np.concatenate(rejected_hist),
+            counts=(
+                {k: np.concatenate([c[k] for c in counts_hist]) for k in counts_hist[0]}
+                if counts_hist
+                else None
+            ),
         )
         if recorder is not None:
             recorder.close(h)

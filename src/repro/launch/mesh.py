@@ -30,7 +30,8 @@ def make_cohort_mesh(n_devices: int | None = None):
 
     Axes:
       cohort — data parallelism over the (K, ...) gathered client lanes;
-               global params and the (C, ...) server slabs stay replicated.
+               global params stay replicated, and so do the (C, ...)
+               server slabs unless each device runs one client (repro.fl.shard).
 
     ``n_devices`` of None/0 takes every visible device; a positive count
     takes a prefix (dev/test runs force host devices via

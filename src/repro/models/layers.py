@@ -44,11 +44,52 @@ def silu(x):
 # ---------------------------------------------------------------------------
 
 
-def _rope_cos_sin(positions: jnp.ndarray, dim: int, base: float = 10000.0):
-    """positions (...,) -> cos, sin of shape (..., dim//2)."""
+def _rope_cos_sin(positions: jnp.ndarray, dim: int, base: float = 10000.0, scaling=None):
+    """positions (...,) -> cos, sin of shape (..., dim//2); ``scaling`` (a
+    ``YarnScaling``) selects YaRN's frequencies and rotation scale."""
     inv = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    mult = 1.0
+    if scaling is not None:
+        inv = yarn_inv_freq(inv, dim, base, scaling)
+        mult = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim
+        )
     ang = positions.astype(jnp.float32)[..., None] * inv  # (..., dim//2)
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * mult, jnp.sin(ang) * mult
+
+
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature term ``0.1 * mscale * ln(scale) + 1``."""
+    if scale <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(inv: jnp.ndarray, dim: int, base: float, scaling) -> jnp.ndarray:
+    """YaRN's rotary frequencies: the original ``inv`` below the
+    ``beta_fast`` correction dimension, ``inv / factor`` past the
+    ``beta_slow`` one, and a linear ramp over the dimensions between."""
+    orig = scaling.original_max_position_embeddings
+
+    def correction_dim(rotations):
+        return dim * math.log(orig / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
+
+
+def attention_scale(cfg: ModelConfig, head_dim: int) -> float:
+    """Softmax scale ``head_dim ** -0.5``, times YaRN's ``mscale ** 2``
+    where the config's rope variant is YaRN."""
+    scale = 1.0 / math.sqrt(head_dim)
+    if cfg.rope_variant == "yarn":
+        m = yarn_mscale(cfg.rope_scaling.factor, cfg.rope_scaling.mscale_all_dim)
+        scale = scale * m * m
+    return scale
 
 
 def _rotate(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
@@ -91,7 +132,8 @@ def apply_rope(
         cos = jnp.concatenate(cos_parts, -1)[:, :, None, :]  # (B,S,1,rope_dim//2)
         sin = jnp.concatenate(sin_parts, -1)[:, :, None, :]
     else:
-        cos, sin = _rope_cos_sin(positions, rope_dim)  # (B,S,rd//2)
+        scaling = cfg.rope_scaling if cfg.rope_variant == "yarn" else None
+        cos, sin = _rope_cos_sin(positions, rope_dim, scaling=scaling)  # (B,S,rd//2)
         cos, sin = cos[:, :, None, :], sin[:, :, None, :]
 
     rot = _rotate(x[..., :rope_dim], cos.astype(jnp.float32), sin.astype(jnp.float32))
@@ -311,17 +353,21 @@ def init_mla(rng, cfg: ModelConfig):
         "wq": (jax.random.normal(k1, (d, h * (nd + rd))) * sd).astype(dt),
         "wdkv": (jax.random.normal(k2, (d, r)) * sd).astype(dt),
         "wkr": (jax.random.normal(k3, (d, rd)) * sd).astype(dt),
+        "kv_norm": jnp.ones((r,), dt),  # RMSNorm on the latent (kv_a_layernorm)
         "wuk": (jax.random.normal(k4, (r, h * nd)) * sd).astype(dt),
         "wuv": (jax.random.normal(k5, (r, h * vd)) * sd).astype(dt),
         "wo": (jax.random.normal(k6, (h * vd, d)) * sd / math.sqrt(2 * cfg.n_layers)).astype(dt),
     }
 
 
+@jax.named_scope("mla.attn")
 def mla_attention(p, x, positions, cfg: ModelConfig, *, cache=None, window=0, mode="train"):
     """Multi-head Latent Attention with decoupled RoPE (arXiv:2405.04434).
 
-    Cache stores the COMPRESSED c_kv (B,T,r) + shared rope key (B,T,rd) —
-    the MLA memory saving; decode re-expands k_nope/v from c_kv.
+    The latent ``c_kv`` passes an RMSNorm (``kv_norm``, the published
+    ``kv_a_layernorm``) before its up-projections. Cache stores the
+    normalised COMPRESSED c_kv (B,T,r) + shared rope key (B,T,rd) — the MLA
+    memory saving; decode re-expands k_nope/v from c_kv.
     """
     b, s, d = x.shape
     h = cfg.n_heads
@@ -329,7 +375,7 @@ def mla_attention(p, x, positions, cfg: ModelConfig, *, cache=None, window=0, mo
 
     q = (x @ p["wq"]).reshape(b, s, h, nd + rd)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
-    c_kv = x @ p["wdkv"]          # (B,S,r)
+    c_kv = rms_norm(x @ p["wdkv"], p["kv_norm"], cfg.norm_eps)  # (B,S,r)
     k_rope = (x @ p["wkr"]).reshape(b, s, 1, rd)
 
     if positions.ndim == 0:  # decode scalar
@@ -348,7 +394,7 @@ def mla_attention(p, x, positions, cfg: ModelConfig, *, cache=None, window=0, mo
         vv = (c @ p["wuv"]).reshape(b, t, h, vd)
         return kn, vv
 
-    scale = 1.0 / math.sqrt(nd + rd)
+    scale = attention_scale(cfg, nd + rd)
     new_cache = None
     if mode in ("train", "prefill"):
         k_nope, v = expand(c_kv)
@@ -430,17 +476,21 @@ def swiglu(p, x):
     return (silu(x @ p["wg"]) * (x @ p["wu"])) @ p["wd"]
 
 
-def init_moe(rng, cfg: ModelConfig):
+def init_moe(rng, cfg: ModelConfig, n_held: int | None = None):
+    """An MoE layer: the router over all ``cfg.n_experts``, the routed
+    experts (``n_held`` of them where given: a chip's share of an
+    expert-parallel group, ``moe_apply_held``), and the shared experts."""
     d, e = cfg.d_model, cfg.n_experts
     dff = cfg.d_ff_expert or cfg.d_ff
+    n = e if n_held is None else n_held
     k1, k2, k3, k4, k5 = jax.random.split(rng, 5)
     sd = 0.02
     dt = jnp.dtype(cfg.dtype)
     p = {
         "router": (jax.random.normal(k1, (d, e)) * sd).astype(jnp.float32),
-        "wg": (jax.random.normal(k2, (e, d, dff)) * sd).astype(dt),
-        "wu": (jax.random.normal(k3, (e, d, dff)) * sd).astype(dt),
-        "wd": (jax.random.normal(k4, (e, dff, d)) * sd / math.sqrt(2 * cfg.n_layers)).astype(dt),
+        "wg": (jax.random.normal(k2, (n, d, dff)) * sd).astype(dt),
+        "wu": (jax.random.normal(k3, (n, d, dff)) * sd).astype(dt),
+        "wd": (jax.random.normal(k4, (n, dff, d)) * sd / math.sqrt(2 * cfg.n_layers)).astype(dt),
     }
     if cfg.n_shared_experts:
         p["shared"] = init_swiglu(k5, cfg, d_ff=dff * cfg.n_shared_experts)
@@ -463,6 +513,18 @@ def moe_apply(p, x, cfg: ModelConfig):
     return moe_apply_local(p, x, cfg)
 
 
+def moe_route(router, xf, cfg: ModelConfig):
+    """Token-choice routing over all ``cfg.n_experts``: float32 softmax
+    scores, greedy top-k. Returns ``(probs (N,E), gate (N,k), idx (N,k))``;
+    the gates are renormalised to sum 1 where ``cfg.norm_topk_prob``."""
+    logits = xf.astype(jnp.float32) @ router                 # (N,E) fp32 router
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, idx = jax.lax.top_k(probs, cfg.top_k)              # (N,k)
+    if cfg.norm_topk_prob:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    return probs, gate, idx
+
+
 def moe_apply_local(p, x, cfg: ModelConfig):
     """Token-choice top-k MoE with per-expert capacity (scatter dispatch).
 
@@ -477,10 +539,7 @@ def moe_apply_local(p, x, cfg: ModelConfig):
     n = b * s
     xf = x.reshape(n, d)
 
-    logits = (xf.astype(jnp.float32)) @ p["router"]          # (N,E) fp32 router
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, idx = jax.lax.top_k(probs, k)                      # (N,k)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    probs, gate, idx = moe_route(p["router"], xf, cfg)        # (N,E), (N,k), (N,k)
 
     # load-balance aux loss (Switch-style): E * sum_e f_e * P_e
     me = jnp.mean(probs, axis=0)                              # (E,)
@@ -511,10 +570,115 @@ def moe_apply_local(p, x, cfg: ModelConfig):
     gflat = gate.reshape(-1)
     y = (gathered * (gflat * keep.astype(jnp.float32))[:, None].astype(x.dtype))
     y = y.reshape(n, k, d).sum(axis=1)
+    if cfg.routed_scaling_factor != 1.0:
+        y = y * cfg.routed_scaling_factor
 
     if cfg.n_shared_experts:
         y = y + swiglu(p["shared"], xf)
     return y.reshape(b, s, d), aux
+
+
+def _one_at_a_time(fn):
+    """``fn`` under ``jax.vmap`` as a loop over the batch: the TPU's grouped
+    matmul takes no batch dimension, and the federated phases vmap a
+    client's step over the clients a device runs."""
+    fn = jax.custom_batching.custom_vmap(fn)
+
+    @fn.def_vmap
+    def rule(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched)]
+        out = jax.lax.map(lambda a: fn(*a), tuple(args))
+        return out, jax.tree.map(lambda _: True, out)
+
+    return fn
+
+
+@_one_at_a_time
+def _ragged(x, w, sizes):
+    return jax.lax.ragged_dot(x, w, sizes)
+
+
+@_one_at_a_time
+def _ragged_vjp(x, w, sizes, g):
+    return jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, sizes), x, w)[1](g)
+
+
+@jax.custom_vjp
+def _grouped(x, w, sizes):
+    """``jax.lax.ragged_dot``: the rows of ``x`` grouped by ``sizes``, group
+    e times ``w[e]``; differentiated and batched by the loops above."""
+    return _ragged(x, w, sizes)
+
+
+def _grouped_fwd(x, w, sizes):
+    return _ragged(x, w, sizes), (x, w, sizes)
+
+
+def _grouped_bwd(res, g):
+    x, w, sizes = res
+    return (*_ragged_vjp(x, w, sizes, g), None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def _held_matmul(x, w, sizes, valid):
+    """The grouped matmul of the rows of ``x`` with the held experts' ``w``,
+    and rows past the last group zeroed: the TPU leaves them unwritten, in
+    the forward and in the transposes alike, so every output of a grouped
+    matmul passes through ``valid``."""
+    return jnp.where(valid[:, None], _grouped(x, w, sizes), 0.0)
+
+
+def moe_apply_held(p, x, cfg: ModelConfig, first_expert: int = 0):
+    """Dropless token-choice MoE over the experts this chip holds.
+
+    Routes every token over all ``cfg.n_experts`` (``moe_route``), and
+    computes only the part of the result that the held experts
+    ``[first_expert, first_expert + n_held)`` give (``p["wg"]`` etc. hold
+    ``n_held`` experts): a (token, expert) pair routed to an absent expert
+    contributes nothing, as on a chip of an expert-parallel group before
+    its exchange. The pairs routed to held experts are sorted by expert
+    into a buffer with room for every pair (``N * top_k`` rows, so none is
+    ever dropped), each held expert runs on its own rows only
+    (``jax.lax.ragged_dot``), and each token sums its rows weighted by its
+    gates. The shared experts run on every token. Returns ``(y, counts)``:
+    ``held`` (pairs routed to held experts) and ``max_load`` (the largest
+    held expert's pairs over the held experts' mean; 0 where none is
+    routed there).
+    """
+    b, s, d = x.shape
+    n_held = p["wg"].shape[0]
+    n = b * s
+    k = cfg.top_k
+    xf = x.reshape(n, d)
+    with jax.named_scope("moe.route"):
+        _, gate, idx = moe_route(p["router"], xf, cfg)
+    with jax.named_scope("moe.dispatch"):
+        local = (idx - first_expert).reshape(-1)                      # (N*k,)
+        expert = jnp.where((local >= 0) & (local < n_held), local, n_held)
+        order = jnp.argsort(expert, stable=True)  # held pairs by expert, absent last
+        loads = jnp.sum(expert[:, None] == jnp.arange(n_held), axis=0)   # (H,)
+        valid = jnp.arange(n * k) < loads.sum()
+        rows = jnp.where(valid[:, None], jnp.take(xf, order // k, axis=0), 0.0)
+    with jax.named_scope("moe.experts"):
+        hg = _held_matmul(rows, p["wg"], loads, valid)
+        hu = _held_matmul(rows, p["wu"], loads, valid)
+        ys = _held_matmul(silu(hg) * hu, p["wd"], loads, valid)         # (N*k, D)
+    with jax.named_scope("moe.combine"):
+        slot = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
+        pairs = jnp.take(ys, slot, axis=0).reshape(n, k, d)  # absent pairs read zeroed rows
+        y = jnp.einsum("nk,nkd->nd", gate.astype(ys.dtype), pairs)
+        if cfg.routed_scaling_factor != 1.0:
+            y = y * cfg.routed_scaling_factor
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe.shared"):
+            y = y + swiglu(p["shared"], xf)
+    held = loads.sum()
+    mean = held.astype(jnp.float32) / n_held
+    max_load = jnp.where(held > 0, loads.max() / jnp.maximum(mean, 1e-9), 0.0)
+    return y.reshape(b, s, d).astype(x.dtype), {"held": held, "max_load": max_load}
 
 
 def moe_apply_ep(p, x, cfg: ModelConfig):
@@ -550,10 +714,7 @@ def moe_apply_ep(p, x, cfg: ModelConfig):
         bl, sl, _ = xl.shape
         nl = bl * sl
         xf = xl.reshape(nl, d)
-        logits = xf.astype(jnp.float32) @ router
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate, idx = jax.lax.top_k(probs, k)
-        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        probs, gate, idx = moe_route(router, xf, cfg)
 
         me = jnp.mean(probs, axis=0)
         one_top = jax.nn.one_hot(idx, e, dtype=jnp.float32)
@@ -584,6 +745,8 @@ def moe_apply_ep(p, x, cfg: ModelConfig):
         gflat = gate.reshape(-1) * keep.astype(jnp.float32)
         y_part = (gathered.astype(jnp.float32) * gflat[:, None]).reshape(nl, k, d).sum(axis=1)
         y = jax.lax.psum(y_part, "model")
+        if cfg.routed_scaling_factor != 1.0:
+            y = y * cfg.routed_scaling_factor
         return y.reshape(bl, sl, d).astype(x.dtype), aux
 
     y, aux = jax.shard_map(

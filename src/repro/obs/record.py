@@ -308,7 +308,8 @@ class RunRecorder:
 
     def on_sync_chunk(self, *, t0: int, acc, sel, pms, wire, tx, times,
                       update_norm, lanes: int, host_gather_ms=None,
-                      staged_bytes=None, rejected=None, dropped=None):
+                      staged_bytes=None, rejected=None, dropped=None,
+                      counts=None):
         """Record one fused chunk from its stacked ``(n, C)`` out leaves —
         one vectorized pass over the chunk, no extra device sync (the
         scheduler already holds the numpy arrays). ``host_gather_ms`` /
@@ -317,7 +318,8 @@ class RunRecorder:
         runs. ``rejected`` ((n,) finite-guard rejections) and ``dropped``
         ((n,) crash/deadline dropouts, fault-mode only) follow the same
         optional-column pattern, with nonzero rounds additionally marked
-        as fault instants on the trace."""
+        as fault instants on the trace; so do the model's ``counts``
+        (``{name: (n,)}``, ``FLHistory.counts``)."""
         n = acc.shape[0]
         acc_mean = acc.mean(axis=1)
         acc_min = acc.min(axis=1)
@@ -362,6 +364,8 @@ class RunRecorder:
                 extra["rejected"] = int(np.asarray(rejected)[i])
             if dropped is not None:
                 extra["dropped"] = int(np.asarray(dropped)[i])
+            for name, values in (counts or {}).items():
+                extra[name] = float(np.asarray(values)[i])
             if tb is not None and (extra.get("rejected") or extra.get("dropped")):
                 tb.instant("fault", PID_SERVER, 0, s1,
                            {"t": int(t0 + i),
