@@ -9,6 +9,11 @@ One chip runs, in one process on ``jax.devices()[0]``:
   har-mlp leaf sizes, vmapped over 30 client lanes. The compiled program
   must hold the Pallas kernel (``tpu_custom_call``), and its codes and
   decoded values must match the jnp reference on the same noise;
+- ef: the int8 codec's error feedback inside its two kernels
+  (``QuantizeCodec.ef_lanes``) for 30 lanes of each har-mlp leaf, against
+  the server model shared by the lanes and against per-lane snapshots,
+  must give what ``ef_step`` on each lane gives on the same chip: the same
+  aggregated values and residuals, and the update norms to 1e-6;
 - sync: ``run_federated`` on the uci-har stand-in (30 clients, har-mlp at
   its published widths 561-256-256-256-6) for a FedAvg float32 baseline
   and the paper recipe with the int8 codec in fused 5-round chunks. The
@@ -55,6 +60,7 @@ import numpy as np  # noqa: E402
 
 # har-mlp leaf sizes: 561x256, 256x256 (twice), 256x6 weights; 256 and 6 biases
 LEAF_SIZES = (143_616, 65_536, 1_536, 256, 6)
+LEAF_SHAPES = ((561, 256), (256, 256), (256, 6), (256,), (6,))
 LANES = 30                # uci-har clients
 ROUNDS = 5
 CHANCE = 1.0 / 6.0        # six activity classes
@@ -180,6 +186,52 @@ def phase_kernel(sizes=LEAF_SIZES, lanes=LANES) -> None:
         checks += [f"{KERNEL_MARK} in all {2 * len(sizes)} programs",
                    f"max |code - ref| = {worst_code:.0f}",
                    f"max |decoded - ref| = {worst_decode:.4f} scale steps"]
+
+
+def phase_ef(shapes=LEAF_SHAPES, lanes=LANES) -> None:
+    from repro.comm.codec import QuantizeCodec, ef_step
+
+    codec = QuantizeCodec(bits=8)
+    check(codec.fused_ef, "the int8 codec does not take the fused pass on the chip")
+    checks = []
+    with phase("ef", checks):
+        worst = {"values": 0.0, "residual": 0.0, "norm": 0.0}
+        for per_lane in (False, True):
+            for shape in shapes:
+                ks = jax.random.split(jax.random.PRNGKey(len(shape) + shape[0]), 4)
+                t = jax.random.normal(ks[0], (lanes,) + shape) * 0.05
+                r = jax.random.normal(ks[1], t.shape) * 1e-3
+                g = jax.random.normal(ks[2], t.shape if per_lane else shape) * 0.05
+                keys = jax.random.split(ks[3], lanes)
+                sent = jnp.arange(lanes) % 3 != 2
+
+                def fused(t, g, r):
+                    return codec.ef_lanes([t], [g], [r], keys, sent)
+
+                def composed(t, g, r):
+                    def lane(t, r, key, g):
+                        dec, new_r = ef_step(codec, [t - g], [r], key)
+                        return g + dec[0], new_r[0]
+                    gs = g if per_lane else jnp.broadcast_to(g, t.shape)
+                    recon, new_r = jax.vmap(lane)(t, r, keys, gs)
+                    keep = sent.reshape((-1,) + (1,) * len(shape))
+                    d = recon - gs
+                    return recon, jnp.where(keep, new_r, r), jnp.sum(
+                        d * d, axis=tuple(range(1, d.ndim)))
+
+                compiled = jax.jit(fused).lower(t, g, r).compile()
+                check(compiled.as_text().count(KERNEL_MARK) >= 2,
+                      f"ef {shape}: no Pallas kernels in the compiled program")
+                (recon,), (new_r,), sq = jax.device_get(compiled(t, g, r))
+                recon_c, new_r_c, sq_c = jax.device_get(jax.jit(composed)(t, g, r))
+                worst["values"] = max(worst["values"], float(np.abs(recon - recon_c).max()))
+                worst["residual"] = max(worst["residual"], float(np.abs(new_r - new_r_c).max()))
+                worst["norm"] = max(worst["norm"], float((np.abs(sq - sq_c) / sq_c).max()))
+        check(worst["values"] == 0.0 and worst["residual"] == 0.0,
+              f"ef: fused and composed differ: {worst}")
+        check(worst["norm"] <= 1e-6, f"ef: squared norms differ by {worst['norm']}")
+        checks += [f"{2 * len(shapes)} leaf programs", "values and residuals equal",
+                   f"max rel. squared-norm gap {worst['norm']:.2e}"]
 
 
 def _sync_configs(rounds=ROUNDS):
@@ -393,6 +445,7 @@ def main() -> None:
         phase_sharded(make_population, devices=4)
     else:
         phase_kernel()
+        phase_ef()
         ds, int8_cfg = phase_sync(make_uci_har)
         phase_async_host(ds, make_population)
         phase_serve(ds, int8_cfg)

@@ -37,12 +37,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.quantize import dequantize, quant_blocks, quantize
+from repro.kernels.quantize import dequantize, ops, quant_blocks, quantize
 
 
 class Codec:
@@ -53,6 +54,9 @@ class Codec:
     # whether the carrier is float32 values a downstream codec can compress
     # further (quantize ships integer codes — terminal in a chain)
     float_carrier: bool = True
+    # whether ``ef_lanes`` runs this codec's error feedback for a stack of
+    # lanes inside its kernels (else the caller composes ``ef_step``)
+    fused_ef: bool = False
 
     # --- wire transform (jit-compatible, static shapes) ---
     def encode(self, flat: jnp.ndarray, rng: jax.Array) -> tuple[Any, jnp.ndarray]:
@@ -163,6 +167,72 @@ class QuantizeCodec(Codec):
         else:
             scales = payload
         return dequantize(carrier, scales, block_p=self.block)
+
+    @property
+    def fused_ef(self) -> bool:
+        """Whether ``ef_lanes`` serves this codec's error feedback: int8
+        where the kernels compile for a TPU."""
+        return self.bits == 8 and not ops._default_interpret()
+
+    def ef_lanes(self, trained, ref, residual, keys: jax.Array, sent: jnp.ndarray):
+        """One error-feedback step of a stack of lanes, the compensation,
+        reconstruction and residual inside the codec's two kernels.
+
+        ``trained`` and ``residual`` are pytrees of stacked leaves (L, ...),
+        ``ref`` the same pytree shared by every lane or stacked, ``keys``
+        (L,) and ``sent`` (L,) bool. Leaf ``i`` of lane ``l`` draws its
+        rounding noise from ``fold_in(keys[l], i)`` as ``ef_step`` does, so
+        the result is ``ef_step`` on each lane's delta ``trained - ref``:
+        returns ``(ref + decoded, new_residual, sq_norm)``, the residual
+        updated only where ``sent``, and each lane's squared norm of
+        ``decoded`` over ``ref`` (L,).
+
+        The server sums what it receives over the lanes in the order the
+        composition's layout gives, so a model aggregated from either is
+        the same to the bit: a leaf of one block is composed per lane as
+        ``ef_step`` composes it (it has no padding or slicing to save), and
+        a leaf the plain wrappers pad reaches the server as the flat
+        (L, size) stack they return."""
+        leaves, treedef = jax.tree_util.tree_flatten(trained)
+        recon, new_res, sq = [], [], 0.0
+        for i, (t, g, r) in enumerate(
+                zip(leaves, jax.tree.leaves(ref), jax.tree.leaves(residual))):
+            n = math.prod(t.shape[1:])
+            if n <= self.block:
+                o, nr = self._ef_composed(t, g, r, keys, i)
+                nr = jnp.where(sent.reshape((-1,) + (1,) * (nr.ndim - 1)), nr, r)
+                d = o - g
+                recon.append(o)
+                new_res.append(nr)
+                sq = sq + jnp.sum(d * d, axis=tuple(range(1, d.ndim)))
+                continue
+            if self.stochastic:
+                noise = jax.vmap(lambda k: jax.random.uniform(
+                    jax.random.fold_in(k, i), t.shape[1:]))(keys)
+            else:
+                noise = jnp.full(t.shape, 0.5, jnp.float32)
+            q, scales, nr = quantize(t, noise, bits=self.bits, block_p=self.block,
+                                     ref=g, residual=r, sent=sent)
+            o, n2 = dequantize(q, scales, block_p=self.block, ref=g, shape=t.shape)
+            if n % quant_blocks(n, self.block)[0]:
+                o = jax.lax.optimization_barrier(o.reshape(o.shape[0], n)).reshape(o.shape)
+            recon.append(o)
+            new_res.append(nr)
+            sq = sq + n2
+        unflat = partial(jax.tree_util.tree_unflatten, treedef)
+        return unflat(recon), unflat(new_res), sq
+
+    def _ef_composed(self, t, g, r, keys, i):
+        """Leaf ``i``'s ``ef_step`` on every lane: ``(ref + decoded,
+        compensated - decoded)``."""
+
+        def lane(t_c, r_c, key, g_c):
+            comp = (t_c - g_c) + r_c
+            dec = self.roundtrip(comp, jax.random.fold_in(key, i))
+            return g_c + dec, comp - dec
+
+        return jax.vmap(lane, in_axes=(0, 0, 0, 0 if g.ndim == t.ndim else None))(
+            t, r, keys, g)
 
     def meta_bytes(self, n):
         _, nb = quant_blocks(n, self.block)

@@ -501,48 +501,45 @@ class TransmitPhase(_Phase):
                 "lossy codec requires RoundState.residual; initialize it with "
                 "jax.tree.map(jnp.zeros_like, local_params) (run_federated does)"
             )
+        agg_src, new_residual, sq_norms = trained, ctx.residual, [None] * len(g)
         if self.codec.lossy:
             # The server aggregates decode(encode(delta + residual)); the new
             # residual absorbs what the codec dropped, but only for clients
             # that actually transmitted the layer (selected AND sharing it) —
             # personalized layers never hit the wire, so their residuals stay.
+            # An int8 codec on a TPU runs each leaf's step for every lane as
+            # its two kernels (the ``codec.ef`` scope), which also return the
+            # lanes' squared update norms.
             agg_src, new_residual = [], []
             for j, (tr_j, g_j, res_j) in enumerate(zip(trained, g, ctx.residual)):
                 sent_j = ctx.select & ctx.share[:, j]  # (lanes,)
                 keys = client_keys(jax.random.fold_in(ctx.rng_codec, j), ctx, env)
+                # async: each client's delta vs its dispatch snapshot
+                ref_j = g_j if base is None else base[j]
+                if self.codec.fused_ef:
+                    with jax.named_scope("codec.ef"):
+                        recon_j, res_j, sq_norms[j] = self.codec.ef_lanes(
+                            tr_j, ref_j, res_j, keys, sent_j)
+                else:
 
-                if base is not None:  # async: delta vs the dispatch snapshot
-
-                    def client_ef_stacked(tr_c, res_c, key, ref_c):
+                    def client_ef(tr_c, res_c, key, ref_c):
                         delta = jax.tree.map(lambda t, gl: t - gl, tr_c, ref_c)
                         dec, new_r = ef_step(self.codec, delta, res_c, key)
                         recon = jax.tree.map(lambda gl, d: gl + d, ref_c, dec)
                         return recon, new_r
 
-                    recon_j, new_r_j = jax.vmap(client_ef_stacked)(
-                        tr_j, res_j, keys, base[j]
-                    )
-                else:
-
-                    def client_ef(tr_c, res_c, key, g_j=g_j):
-                        delta = jax.tree.map(lambda t, gl: t - gl, tr_c, g_j)
-                        dec, new_r = ef_step(self.codec, delta, res_c, key)
-                        recon = jax.tree.map(lambda gl, d: gl + d, g_j, dec)
-                        return recon, new_r
-
-                    recon_j, new_r_j = jax.vmap(client_ef)(tr_j, res_j, keys)
-                agg_src.append(recon_j)
-                new_residual.append(
-                    jax.tree.map(
+                    recon_j, new_r_j = jax.vmap(
+                        client_ef, in_axes=(0, 0, 0, None if base is None else 0)
+                    )(tr_j, res_j, keys, ref_j)
+                    res_j = jax.tree.map(
                         lambda n, o: jnp.where(
                             sent_j.reshape((-1,) + (1,) * (n.ndim - 1)), n, o
                         ),
                         new_r_j,
                         res_j,
                     )
-                )
-        else:  # lossless: the wire carries the exact update, no residual
-            agg_src, new_residual = trained, ctx.residual
+                agg_src.append(recon_j)
+                new_residual.append(res_j)
 
         # --- cost signals for selection + accounting ------------------------
         # lane-level (cohort) versions; the engine computes the population
@@ -551,9 +548,10 @@ class TransmitPhase(_Phase):
         wire_prospective, wire_paid = self.wire_costs(g, ctx.share, ctx.select)
         share_f = ctx.share.astype(jnp.float32)
         norm_sq = 0.0
-        for j in range(len(g)):
+        for j, sq_j in enumerate(sq_norms):
             ref_j = base[j] if base is not None else g[j]
-            norm_sq = norm_sq + share_f[:, j] * _client_sq_norms(agg_src[j], ref_j)
+            norm_sq = norm_sq + share_f[:, j] * (
+                _client_sq_norms(agg_src[j], ref_j) if sq_j is None else sq_j)
         return ctx._replace(
             agg_src=agg_src,
             residual=new_residual,

@@ -47,6 +47,78 @@ def test_quantize_kernel_matches_ref(n, bits):
     np.testing.assert_allclose(np.asarray(out), np.asarray(outr), rtol=1e-6)
 
 
+# har-mlp's leaves: the 561x256 input weight (281 blocks, the last half
+# full), the 256x256 hidden weights, the 256x6 output weight, the biases;
+# and a 128-wide weight, four rows a block, its last block one row
+EF_LEAVES = [(561, 256), (256, 256), (256, 6), (256,), (6,), (301, 128)]
+
+
+@pytest.mark.parametrize("per_lane_ref", [False, True], ids=["shared", "per_lane"])
+@pytest.mark.parametrize("shape", EF_LEAVES, ids=lambda s: "x".join(map(str, s)))
+def test_fused_error_feedback_matches_ef_step(shape, per_lane_ref):
+    """The int8 codec's error feedback inside its two kernels (interpret
+    mode, a few lanes) against today's composition: ``ef_step`` on each
+    lane's delta from its reference (the server model shared by the lanes,
+    or each lane's dispatch snapshot), the residual kept where the lane did
+    not send, one lane holding a NaN. Codes, scales, what the server
+    aggregates and the new residual are bit-equal; the update norm agrees
+    to float32 summation order."""
+    lanes, codec = 3, QuantizeCodec(bits=8)
+    ks = jax.random.split(jax.random.PRNGKey(len(shape) + shape[0]), 5)
+    trained = jax.random.normal(ks[0], (lanes,) + shape) * 2.0
+    trained = trained.at[(1,) + (3,) * len(shape)].set(jnp.nan)
+    residual = jax.random.normal(ks[1], trained.shape) * 0.05
+    ref = jax.random.normal(ks[2], trained.shape if per_lane_ref else shape)
+    keys = jax.random.split(ks[3], lanes)
+    sent = jnp.array([True, True, False])
+
+    @jax.jit
+    def fused(trained, ref, residual):
+        noise = jax.vmap(lambda k: jax.random.uniform(jax.random.fold_in(k, 0), shape))(keys)
+        q, s, _ = quantize(trained, noise, ref=ref, residual=residual, sent=sent)
+        recon, new_res, sq = codec.ef_lanes([trained], [ref], [residual], keys, sent)
+        return q, s, recon[0], new_res[0], sq
+
+    @jax.jit
+    def composed(trained, ref, residual):
+        def lane(t, r, key, g):
+            dec, new_r = ef_step(codec, [t - g], [r], key)
+            return g + dec[0], new_r[0], quantize(
+                ((t - g) + r).reshape(-1),
+                jax.random.uniform(jax.random.fold_in(key, 0), (t.size,)))
+        refs = ref if per_lane_ref else jnp.broadcast_to(ref, trained.shape)
+        recon, new_res, (q, s) = jax.vmap(lane)(trained, residual, keys, refs)
+        new_res = jnp.where(sent.reshape((-1,) + (1,) * len(shape)), new_res, residual)
+        d = recon - refs
+        return q, s, recon, new_res, jnp.sum(d * d, axis=tuple(range(1, d.ndim)))
+
+    q, s, recon, new_res, sq = fused(trained, ref, residual)
+    q_ref, s_ref, recon_ref, new_res_ref, sq_ref = composed(trained, ref, residual)
+    # the blocked codes (L, G, NB, C) in wire order: block b holds rows b*G..
+    flat = jnp.swapaxes(q, 1, 2).reshape(lanes, -1)[:, :q_ref.shape[1]]
+    np.testing.assert_array_equal(np.asarray(flat), np.asarray(q_ref))
+    np.testing.assert_array_equal(np.asarray(s[..., 0]), np.asarray(s_ref))
+    np.testing.assert_array_equal(np.asarray(recon), np.asarray(recon_ref))
+    np.testing.assert_array_equal(np.asarray(new_res), np.asarray(new_res_ref))
+    np.testing.assert_allclose(np.asarray(sq), np.asarray(sq_ref), rtol=1e-6)
+    assert np.isnan(np.asarray(sq)[1]) and np.isfinite(np.asarray(sq)[[0, 2]]).all()
+    np.testing.assert_array_equal(np.asarray(new_res[2]), np.asarray(residual[2]))
+
+
+def test_fused_error_feedback_only_for_int8_on_a_tpu(monkeypatch):
+    """The fused pass is chosen from what the code sees: an int8 codec
+    where the kernels compile for a TPU. Off the TPU and for int4 every
+    lossy codec keeps ``ef_step``."""
+    from repro.kernels.quantize import ops
+
+    assert not QuantizeCodec(bits=8).fused_ef  # the CPU here
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    assert QuantizeCodec(bits=8).fused_ef
+    assert not QuantizeCodec(bits=4).fused_ef
+    assert not any(getattr(c, "fused_ef", False) for c in (
+        Float32Identity(), TopKCodec(0.1), ChainedCodec([TopKCodec(0.1), QuantizeCodec(8)])))
+
+
 def test_quantize_ops_pad_ragged_sizes():
     """The jit wrappers pad ragged sizes to whole blocks and slice back."""
     for n in (7, 513, 1000):

@@ -13,6 +13,7 @@ workers all import this file.
 """
 
 import dataclasses
+import math
 import os
 import re
 
@@ -21,10 +22,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.comm.codec import QuantizeCodec
 from repro.kernels.quantize import dequantize, quantize
 
 # har-mlp leaf sizes: 561x256, 256x256, 256x6 weights; 256 and 6 biases
 LEAF_SIZES = (143_616, 65_536, 1_536, 256, 6)
+LEAF_SHAPES = ((561, 256), (256, 256), (256, 6), (256,), (6,))
 LANES = 30  # uci-har clients
 KERNEL = "tpu_custom_call"
 
@@ -76,12 +79,27 @@ def test_quantize_pair_compiles_for_v5e(one_chip, bits, n):
     assert text.count(KERNEL) >= 2, "quantize and dequantize kernels"
 
 
-@pytest.fixture(scope="module")
-def int8_round_text(one_chip):
-    """The optimized program of one round of the paper recipe with the int8
-    codec, on the uci-har stand-in at har-mlp's published widths, compiled
-    for one chip with the quantize wrappers picking the Pallas kernel as
-    they do on a TPU (traces made meanwhile are dropped on both sides)."""
+@pytest.mark.parametrize("per_lane_ref", [False, True], ids=["shared", "per_lane"])
+@pytest.mark.parametrize("shape", LEAF_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_fused_error_feedback_compiles_for_v5e(one_chip, shape, per_lane_ref):
+    """The int8 codec's error-feedback pair for the 30 client lanes of a
+    har-mlp leaf, against the server model shared by the lanes (sync) or
+    each lane's dispatch snapshot (async), compiles for the chip as two
+    Pallas kernels."""
+
+    def step(t, ref, res, u, sent):
+        q, s, new_res = quantize(t, u, ref=ref, residual=res, sent=sent, interpret=False)
+        recon, sq = dequantize(q, s, ref=ref, shape=t.shape, interpret=False)
+        return recon, new_res, sq
+
+    lanes = jax.ShapeDtypeStruct((LANES,) + shape, jnp.float32, sharding=one_chip)
+    ref = lanes if per_lane_ref else jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    sent = jax.ShapeDtypeStruct((LANES,), jnp.bool_, sharding=one_chip)
+    text = jax.jit(step).lower(lanes, ref, lanes, lanes, sent).compile().as_text()
+    assert text.count(f'custom_call_target="{KERNEL}"') == 2
+
+
+def _int8_round_text(one_chip, fused=True):
     from repro.configs.har_mlp import fl_defaults
     from repro.data import make_har_dataset
     from repro.fl import api
@@ -98,6 +116,8 @@ def int8_round_text(one_chip):
     jax.clear_caches()
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_default_interpret", lambda: False)
+        if not fused:
+            mp.setattr(QuantizeCodec, "fused_ef", False)
         su = _setup_run(ds, cfg, None, mlp_loss, mlp_accuracy, None, None, None)
         state = su.initial_state()
 
@@ -109,6 +129,15 @@ def int8_round_text(one_chip):
         text = jax.jit(round_step).lower(*_abstract(args, one_chip)).compile().as_text()
     jax.clear_caches()
     return text
+
+
+@pytest.fixture(scope="module")
+def int8_round_text(one_chip):
+    """The optimized program of one round of the paper recipe with the int8
+    codec, on the uci-har stand-in at har-mlp's published widths, compiled
+    for one chip with the quantize wrappers picking the Pallas kernel as
+    they do on a TPU (traces made meanwhile are dropped on both sides)."""
+    return _int8_round_text(one_chip)
 
 
 def test_int8_round_step_compiles_for_v5e(int8_round_text):
@@ -132,3 +161,67 @@ def test_codec_kernels_keep_the_name_the_benchmark_finds(int8_round_text):
         name = line.strip().removeprefix("ROOT ").split(" = ", 1)[0]
         assert CODEC.search(name), name
         assert "/fl.transmit/" in re.search(r'op_name="([^"]*)"', line).group(1)
+
+
+def _transmit_ops(text):
+    """``(name, opcode, shape, line)`` of each instruction of the optimized
+    program in the ``fl.transmit`` scope, outside fusion bodies."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    comp, ops = None, []
+    for line in text.splitlines():
+        if line and not line.startswith(" ") and line.rstrip().endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            continue
+        scope = re.search(r'op_name="([^"]*)"', line)
+        if comp in fused or not scope or "/fl.transmit/" not in scope.group(1):
+            continue
+        name, rest = line.strip().removeprefix("ROOT ").split(" = ", 1)
+        opcode = re.search(r" ([a-z][a-z0-9\-]*)\(", rest).group(1)
+        dims = re.search(r"\[([\d,]*)\]", rest).group(1)
+        ops.append((name, opcode, tuple(int(d) for d in dims.split(",") if d), line))
+    return ops
+
+
+def test_int8_transmit_runs_error_feedback_inside_the_kernels(int8_round_text):
+    """The int8 round's uplink, as a TPU runs it: each leaf's error
+    feedback for all 30 lanes inside the codec's two kernels, under the
+    ``codec.ef`` scope. No pad or slice copies a lane stack of a large leaf
+    around them (the generic composition pads the 561x256 leaf's values and
+    noise and slices codes and values back), and the compiler's estimate
+    of the scope's other ops stays under 0.9 M cycles (1.6 M with the
+    generic composition)."""
+    ops = _transmit_ops(int8_round_text)
+    kernels = [line for _, _, _, line in ops if f'custom_call_target="{KERNEL}"' in line]
+    assert len(kernels) == 16
+    assert all("/fl.transmit/codec.ef/" in line for line in kernels)
+    copies = [name for name, opcode, shape, _ in ops if opcode in ("pad", "slice")
+              and shape[:1] == (LANES,) and math.prod(shape[1:]) >= 65_536]
+    assert copies == []
+    cycles = sum(int(c) for _, _, _, line in ops if KERNEL not in line
+                 for c in re.findall(r'"estimated_cycles":"(\d+)"', line))
+    assert 0 < cycles <= 900_000, cycles
+
+
+def _lane_sums(text):
+    """``(size, where)`` of each lane sum of ``fl.aggregate`` in the
+    optimized program: the size of what it reduces to, and where the lane
+    axis lies in its operand's layout (the vector lanes, the sublanes, or a
+    major axis), which sets the order of the additions."""
+    sums = []
+    for m in re.finditer(r"= f32\[(\d+),([\d,]+)\]\{([\d,]+):T[^}]*\} multiply\(.*\n"
+                         r".*\n.*ROOT %reduce[\w.]* = .*fl\.aggregate/reduce_sum", text):
+        minor_to_major = [int(d) for d in m.group(3).split(",")]
+        where = {0: "lanes", 1: "sublanes"}.get(minor_to_major.index(0), "major")
+        sums.append((math.prod(int(d) for d in m.group(2).split(",")), where))
+    return sorted(sums)
+
+
+def test_int8_aggregation_adds_lanes_in_the_compositions_order(one_chip, int8_round_text):
+    """The server's sum over the 30 lanes of each leaf runs over the same
+    layout with the fused pass as with the ``ef_step`` composition, so the
+    global model the two aggregate is the same to the bit: the 561x256
+    leaf in the flat stack the plain wrappers return (lanes on sublanes),
+    the 6-wide output bias composed as the composition composes it."""
+    fused = _lane_sums(int8_round_text)
+    assert fused == _lane_sums(_int8_round_text(one_chip, fused=False))
+    assert len(fused) == 8 and (143_616, "sublanes") in fused and (6, "sublanes") in fused
